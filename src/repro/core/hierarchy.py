@@ -70,10 +70,10 @@ class MediumSpec:
     ``residency == "pinned_host"``.
 
     ``pinned_host`` is the NVM/CXL analogue with device addressability:
-    the pool is one jax buffer placed in pinned host memory (plain host
-    placement where the backend has no memory kinds), so migrations in
-    and out of it stay inside the jax runtime (donated scatters instead
-    of numpy staging copies), the fused serving dispatch can append KV
+    the pool is one jax buffer placed in pinned host memory (the backend
+    must expose that memory kind), so migrations in and out of it stay
+    inside the jax runtime (one-program scatters instead of numpy
+    staging copies), the fused serving dispatch can append KV
     into it and charge its wear counters on device, and int8
     quantization fuses into the demotion gather as one kernel.
     """
@@ -209,7 +209,7 @@ class MemoryHierarchy:
         pool simulates the DRAM channel (device<->device migration stays
         on-accelerator), backed by the host NVM-analogue tier with wear
         telemetry.  ``pinned_nvm`` makes the NVM tier a pinned-host jax
-        pool (device-addressable, donated demotion commits)."""
+        pool (device-addressable, one-program demotion commits)."""
         return cls(tiers=(
             MediumSpec("HBM", hbm_slots, cm.HBM, residency=DEVICE),
             MediumSpec("DRAM", dram_slots, cm.DRAM, residency=DEVICE),

@@ -764,7 +764,7 @@ class BatchedMigrationEngine:
                     src_slots: np.ndarray, dst_slots: np.ndarray) -> None:
         """Bulk-move one (src, dst) tier pair's data by residency:
         device-addressable pairs (device and pinned-host tiers) stay
-        inside the jax runtime — gather + donated scatter, with int8
+        inside the jax runtime — gather + scatter programs, with int8
         quantization fused into the pinned pool's scatter — the
         device<->numpy-host pairs go through chunked staging, and
         host->host is one vectorized numpy copy.

@@ -12,8 +12,9 @@ Logical pages live in one of the pools described by a
     and storing bfloat16 payloads as their uint16 bit-pattern (no silent
     widening to float32);
   * **pinned_host** tiers — host-capacity jax pools addressable from
-    device code: migrations donate the buffer instead of staging numpy
-    copies, int8 quantization fuses into the gather/scatter dispatch,
+    device programs: migrations scatter into them in one program instead
+    of staging numpy copies, int8 quantization fuses into the
+    gather/scatter dispatch,
     and the fused serving dispatch appends KV and charges wear counters
     into them directly.
 
@@ -51,9 +52,9 @@ import numpy as np
 
 from repro.faults.injector import get_injector
 from repro.faults.integrity import PageIntegrity
+from repro.kernels.page_checksum import page_checksum
 from repro.kernels.page_gather import (page_gather, page_gather_dequant,
-                                       page_gather_quant, page_scatter,
-                                       page_scatter_quant)
+                                       page_scatter, page_scatter_quant)
 
 from .allocator import SubBuddyAllocator, SubBuddyConfig
 from .hierarchy import MediumSpec, MemoryHierarchy
@@ -316,29 +317,97 @@ class HostPool:
             self.scale[[a, b]] = self.scale[[b, a]]
 
 
-def _pin_host(x: jnp.ndarray) -> jnp.ndarray:
-    """Place a jax array in pinned host memory where the backend supports
-    memory kinds (TPU/GPU); plain default placement otherwise (on the CPU
-    backend every buffer already lives in host RAM)."""
-    try:
-        dev = x.devices().pop() if hasattr(x, "devices") else jax.devices()[0]
-        sharding = jax.sharding.SingleDeviceSharding(
-            dev, memory_kind="pinned_host")
-        return jax.device_put(x, sharding)
-    except (ValueError, NotImplementedError, TypeError):
-        return x
+def _pin_host(x) -> jnp.ndarray:
+    """Place ``x`` (numpy or jax) in the default device's pinned host
+    memory.  Already-pinned arrays pass through without a copy.  A
+    backend whose device exposes no ``pinned_host`` memory cannot hold a
+    pinned-host tier, and says so."""
+    dev = jax.devices()[0]
+    kinds = sorted(m.kind for m in dev.addressable_memories())
+    if "pinned_host" not in kinds:
+        raise ValueError(
+            f"a pinned_host tier needs pinned host memory, but {dev} "
+            f"({dev.platform}) exposes only {kinds}; back the tier with "
+            f"residency='host' instead")
+    return jax.device_put(x, jax.sharding.SingleDeviceSharding(
+        dev, memory_kind="pinned_host"))
+
+
+# Device code never reads host memory directly: every program over a
+# pinned pool moves the pool into device memory first and places the
+# updated pool back in pinned host memory on the way out.  The TPU
+# compiler turns the two placements into host<->device copies; backends
+# that keep all memory on the host (CPU) treat them as no-ops and return
+# the result in device memory, which ``PinnedHostPool.data`` pins again.
+# Pinned pools are never donated: the CPU runtime aborts when a donated
+# pinned buffer is aliased to a device-memory result, and on the TPU the
+# pool is copied in and out anyway, so donation would only save the
+# second host-side buffer.
+
+def to_device_memory(x: jnp.ndarray) -> jnp.ndarray:
+    """Inside a program: ``x`` moved into device memory."""
+    return jax.device_put(x, jax.memory.Space.Device)
+
+
+def to_pinned_host(x: jnp.ndarray) -> jnp.ndarray:
+    """Inside a program: ``x`` placed in pinned host memory."""
+    return jax.device_put(x, jax.memory.Space.Host)
+
+
+@jax.jit
+def pinned_gather(pool, idx):
+    """staging[i] = pool[idx[i]] for a pinned ``pool``; staging on device."""
+    return page_gather(to_device_memory(pool), idx)
+
+
+@jax.jit
+def pinned_scatter(pool, idx, pages):
+    """pool[idx[i]] = pages[i] for a pinned ``pool``."""
+    return to_pinned_host(page_scatter(to_device_memory(pool), idx, pages))
+
+
+@jax.jit
+def _pinned_gather_dequant(pool_q, pool_scale, idx):
+    return page_gather_dequant(to_device_memory(pool_q),
+                               to_device_memory(pool_scale), idx)
+
+
+@jax.jit
+def _pinned_scatter_quant(pool_q, pool_scale, idx, pages):
+    q, scale = page_scatter_quant(to_device_memory(pool_q),
+                                  to_device_memory(pool_scale), idx, pages)
+    return to_pinned_host(q), to_pinned_host(scale)
+
+
+@jax.jit
+def _pinned_swap_rows(pool, pair, rev):
+    pool = to_device_memory(pool)
+    return to_pinned_host(pool.at[pair].set(pool[rev]))
+
+
+@jax.jit
+def _pinned_token_write(pool, phys, offset, layer_kv):
+    pool = to_device_memory(pool)
+    return to_pinned_host(
+        pool.at[phys, :, :, offset].set(layer_kv.astype(pool.dtype)))
+
+
+@jax.jit
+def _pinned_checksum(pool, idx):
+    return page_checksum(to_device_memory(pool), idx)
 
 
 class PinnedHostPool:
-    """A host-capacity page pool addressable from device code.
+    """A host-capacity page pool addressable from device programs.
 
-    The pool is a single jax buffer placed in pinned host memory
-    (``memory_kind="pinned_host"`` where the backend supports it, plain
-    placement otherwise), so migration engines gather/scatter it inside
-    the jax runtime — demotion commits *donate* the pool buffer through
-    ``page_scatter`` instead of staging a numpy copy — and the fused
-    serving dispatch can append KV into it and bump its wear counters
-    without a host round trip.
+    The pool is a single jax buffer in pinned host memory (the backend
+    must expose ``memory_kind="pinned_host"``), so migration engines
+    gather/scatter it inside the jax runtime — demotion commits scatter
+    into it in one program instead of staging a numpy copy — and the fused
+    serving dispatch appends KV into it and bumps its wear counters
+    without a host round trip.  Every program over the pool moves it to
+    device memory and back explicitly (``to_device_memory`` /
+    ``to_pinned_host``); assigning ``data`` re-pins the result.
 
     ``quantize_int8`` keeps the pool as int8 + per-page scale with the
     quantization fused into the gather/scatter dispatch
@@ -353,13 +422,29 @@ class PinnedHostPool:
         self.page_shape = page_shape
         self.dtype = dtype
         self.quantized = spec.quantize_int8
-        self.scale = None
+        self._scale = None
+        shape = (spec.slots, *page_shape)
         if self.quantized:
-            self.data = _pin_host(jnp.zeros((spec.slots, *page_shape),
-                                            jnp.int8))
-            self.scale = _pin_host(jnp.ones((spec.slots,), jnp.float32))
+            self.data = np.zeros(shape, np.int8)
+            self.scale = np.ones((spec.slots,), np.float32)
         else:
-            self.data = _pin_host(jnp.zeros((spec.slots, *page_shape), dtype))
+            self.data = np.zeros(shape, jnp.dtype(dtype))
+
+    @property
+    def data(self) -> jnp.ndarray:
+        return self._data
+
+    @data.setter
+    def data(self, value) -> None:
+        self._data = _pin_host(value)
+
+    @property
+    def scale(self) -> jnp.ndarray | None:
+        return self._scale
+
+    @scale.setter
+    def scale(self, value) -> None:
+        self._scale = None if value is None else _pin_host(value)
 
     # -- HostPool-compatible per-physical-slot API -----------------------------
     def write_one(self, phys: int, value: np.ndarray) -> None:
@@ -375,35 +460,57 @@ class PinnedHostPool:
         k = np.asarray(phys).size
         return np.asarray(self.gather(phys), np.float32)[:k]
 
+    # -- raw stored rows (integrity checksums, fault injection) ----------------
+    def read_raw(self, phys: int) -> np.ndarray:
+        """One physical row's stored representation (int8 when quantized)."""
+        return np.array(pinned_gather(self.data,
+                                      jnp.asarray([phys], jnp.int32))[0])
+
+    def write_raw(self, phys: int, row: np.ndarray) -> None:
+        self.data = pinned_scatter(self.data, jnp.asarray([phys], jnp.int32),
+                                   jnp.asarray(row[None], self.data.dtype))
+
+    def checksums(self, phys) -> jnp.ndarray:
+        """uint32 checksums of the stored bits of rows ``phys`` (padded)."""
+        return _pinned_checksum(self.data, jnp.asarray(phys, jnp.int32))
+
     # -- device-addressable bulk API (jax in, jax out) -------------------------
     def gather(self, phys) -> jnp.ndarray:
         """Pow2-padded gather, like :meth:`DevicePool.gather` (fused
-        dequantize for int8 pools)."""
+        dequantize for int8 pools); the staging buffer is in device
+        memory."""
         idx = jnp.asarray(_pad_idx_np(phys), jnp.int32)
         if self.quantized:
-            return page_gather_dequant(self.data, self.scale, idx)
-        return page_gather(self.data, idx)
+            return _pinned_gather_dequant(self.data, self.scale, idx)
+        return pinned_gather(self.data, idx)
 
     def scatter(self, phys, pages) -> None:
-        """pool[phys[i]] = pages[i], pool buffer donated; fuses the int8
-        quantize into the same dispatch for quantized pools."""
+        """pool[phys[i]] = pages[i]; fuses the int8 quantize into the
+        same dispatch for quantized pools."""
         idx = _pad_idx_np(phys)
         pages = _pad_pages(pages, idx.size)
         idx = jnp.asarray(idx, jnp.int32)
         if self.quantized:
-            self.data, self.scale = page_scatter_quant(
+            self.data, self.scale = _pinned_scatter_quant(
                 self.data, self.scale, idx,
                 jnp.asarray(pages).astype(jnp.float32))
         else:
-            self.data = page_scatter(self.data, idx,
-                                     jnp.asarray(pages).astype(self.dtype))
+            self.data = pinned_scatter(self.data, idx,
+                                       jnp.asarray(pages).astype(self.dtype))
+
+    def write_token(self, phys: int, offset: int, layer_kv) -> None:
+        """One token's K/V ([L, 2, Hkv, Dh]) into row ``phys`` at in-page
+        ``offset``, in one program (no host read-modify-write)."""
+        self.data = _pinned_token_write(self.data, jnp.int32(phys),
+                                        jnp.int32(offset),
+                                        jnp.asarray(layer_kv))
 
     def swap_rows(self, a: int, b: int) -> None:
         pair = jnp.asarray([a, b], jnp.int32)
         rev = jnp.asarray([b, a], jnp.int32)
-        self.data = self.data.at[pair].set(self.data[rev])
+        self.data = _pinned_swap_rows(self.data, pair, rev)
         if self.scale is not None:
-            self.scale = self.scale.at[pair].set(self.scale[rev])
+            self.scale = _pinned_swap_rows(self.scale, pair, rev)
 
 
 class _LevelerView:
@@ -707,10 +814,10 @@ class TierStore:
         return self.pools[tier].gather(slots)
 
     def scatter_device(self, tier: int, slots, pages: jnp.ndarray) -> None:
-        """pool[slots[i]] = pages[i] on a device-addressable tier (pool
-        donated).  Pinned-host tiers go through the wear remap, fuse int8
+        """pool[slots[i]] = pages[i] on a device-addressable tier.
+        Pinned-host tiers go through the wear remap, fuse int8
         quantization into the same dispatch, and charge wear counters —
-        the demotion commit donates the slow pool instead of copying."""
+        the demotion commit is one program, not a numpy staging copy."""
         if self.is_pinned_tier(tier):
             phys = self._phys(tier, np.asarray(slots, np.int64))
             self.pools[tier].scatter(phys, pages)

@@ -155,10 +155,10 @@ class FaultInjector:
             flat = pool.data[phys].view(np.uint8).reshape(-1)
             flat[byte] ^= np.uint8(1 << bit)
         else:                      # pinned jax pool: round-trip one row
-            row = np.array(pool.data[phys])
+            row = pool.read_raw(phys)
             flat = row.view(np.uint8).reshape(-1)
             flat[byte] ^= np.uint8(1 << bit)
-            pool.data = pool.data.at[phys].set(row)
+            pool.write_raw(phys, row)
 
     @staticmethod
     def _force_bit(pool, phys: int, byte: int, bit: int, val: int) -> bool:
@@ -172,10 +172,10 @@ class FaultInjector:
 
         if isinstance(pool.data, np.ndarray):
             return apply(pool.data[phys].view(np.uint8).reshape(-1))
-        row = np.array(pool.data[phys])
+        row = pool.read_raw(phys)
         changed = apply(row.view(np.uint8).reshape(-1))
         if changed:
-            pool.data = pool.data.at[phys].set(row)
+            pool.write_raw(phys, row)
         return changed
 
     # -- site 2: async plan worker --------------------------------------------

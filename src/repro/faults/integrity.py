@@ -44,16 +44,14 @@ class PageIntegrity:
     def slot_checksums(self, store, tier: int, slots) -> np.ndarray:
         # kernel import is deferred: repro.kernels pulls in repro.core,
         # which imports this module — a top-level import would cycle
-        from repro.kernels.page_checksum import checksum_np, page_checksum
+        from repro.kernels.page_checksum import checksum_np
         slots = np.asarray(slots, np.int64).reshape(-1)
         phys = store._phys(tier, slots)
         pool = store.pools[tier]
         if isinstance(pool.data, np.ndarray):
             return checksum_np(pool.data[phys])
         # pinned jax pool: one checksum dispatch over the padded row list
-        import jax.numpy as jnp
-        idx = _pad_pow2(phys)
-        out = np.asarray(page_checksum(pool.data, jnp.asarray(idx, jnp.int32)))
+        out = np.asarray(pool.checksums(_pad_pow2(phys)))
         return out[:slots.size]
 
     # -- lifecycle -------------------------------------------------------------

@@ -1,9 +1,9 @@
 """jit'd public wrapper for the flash attention kernel.
 
 Handles: GQA layout flattening, qk scaling, head_dim padding to a 128
-multiple (MXU lane width), and seq padding to block multiples.  On
-non-TPU backends it falls back to interpret mode (CPU validation) —
-production serving/training on TPU lowers the real kernel.
+multiple (MXU lane width), and seq padding to block multiples.  The
+kernel is compiled for the TPU; ``interpret=True`` (kernel-parity tests)
+runs it in the Pallas interpreter.
 """
 from __future__ import annotations
 
@@ -29,10 +29,8 @@ def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, window: int = 0,
                     bq: int = 128, bk: int = 256,
-                    interpret: bool | None = None) -> jnp.ndarray:
+                    interpret: bool = False) -> jnp.ndarray:
     """q: [B, Sq, Hq, D]; k/v: [B, Sk, Hkv, D] -> [B, Sq, Hq, D]."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
     scale = D ** -0.5

@@ -104,15 +104,17 @@ def sysmon_pass_pallas(reads: jnp.ndarray, writes: jnp.ndarray,
 def _touch_kernel(ids_ref, r_ref, w_ref,
                   dr_ref, dw_ref, touched_ref, *, block: int):
     i = pl.program_id(0)
-    # pages owned by this grid step, as a [block, 1] column
-    pages = i * block + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
-    ids = ids_ref[...].astype(jnp.int32).reshape(1, -1)     # [1, k]
-    r = r_ref[...].astype(jnp.int32).reshape(1, -1)
-    w = w_ref[...].astype(jnp.int32).reshape(1, -1)
-    hit = pages == ids                                      # [block, k]
-    dr_ref[...] = jnp.sum(jnp.where(hit, r, 0), axis=1)
-    dw_ref[...] = jnp.sum(jnp.where(hit, w, 0), axis=1)
-    touched_ref[...] = jnp.max(jnp.where(hit, r + w, 0), axis=1)
+    # events down the sublanes ([k, 1] columns), the pages owned by this
+    # grid step across the lanes ([1, block] row): the hit matrix reduces
+    # over sublanes into lane-dense outputs
+    pages = i * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    hit = ids_ref[...] == pages                             # [k, block]
+    r = r_ref[...]
+    w = w_ref[...]
+    dr_ref[...] = jnp.sum(jnp.where(hit, r, 0), axis=0, keepdims=True)
+    dw_ref[...] = jnp.sum(jnp.where(hit, w, 0), axis=0, keepdims=True)
+    touched_ref[...] = jnp.max(jnp.where(hit, r + w, 0), axis=0,
+                               keepdims=True)
 
 
 def touch_update_pallas(n_pages: int, page_ids: jnp.ndarray,
@@ -133,15 +135,15 @@ def touch_update_pallas(n_pages: int, page_ids: jnp.ndarray,
     n_full = n_pages + npad
     kernel = functools.partial(_touch_kernel, block=block)
     kfull = page_ids.shape[0]
-    espec = pl.BlockSpec((kfull,), lambda i: (0,))   # every step sees all ids
-    pspec = pl.BlockSpec((block,), lambda i: (i,))
+    espec = pl.BlockSpec((kfull, 1), lambda i: (0, 0))  # every step sees all ids
+    pspec = pl.BlockSpec((1, block), lambda i: (0, i))
+    col = lambda a: a.astype(jnp.int32).reshape(kfull, 1)  # noqa: E731
     out = pl.pallas_call(
         kernel,
         grid=(n_full // block,),
         in_specs=[espec, espec, espec],
         out_specs=[pspec, pspec, pspec],
-        out_shape=[jax.ShapeDtypeStruct((n_full,), jnp.int32)] * 3,
+        out_shape=[jax.ShapeDtypeStruct((1, n_full), jnp.int32)] * 3,
         interpret=interpret,
-    )(page_ids.astype(jnp.int32), reads.astype(jnp.int32),
-      writes.astype(jnp.int32))
-    return tuple(o[:n_pages] for o in out)
+    )(col(page_ids), col(reads), col(writes))
+    return tuple(o[0, :n_pages] for o in out)

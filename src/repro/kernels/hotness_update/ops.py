@@ -1,6 +1,7 @@
 """jit'd wrappers for the fused SysMon kernels.
 
-``touch_update`` follows the ``kernels/wear_update`` dispatch discipline:
+``sysmon_pass`` and ``touch_update`` follow the ``kernels/wear_update``
+dispatch discipline:
 
   * TPU            — the blocked Pallas histogram kernel, compiled;
   * explicit       — ``interpret=True`` runs the Pallas kernel in
@@ -19,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from .hotness_update import sysmon_pass_pallas, touch_update_pallas
+from .ref import sysmon_pass_ref
 
 
 @partial(jax.jit, static_argnames=("window_len", "k_len", "hi", "lo",
@@ -27,7 +29,10 @@ def sysmon_pass(reads, writes, hist, *, window_len: int = 8, k_len: int = 3,
                 hi: int = 6, lo: int = 2, block: int = 1024,
                 interpret: bool | None = None):
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        if jax.default_backend() != "tpu":
+            return sysmon_pass_ref(reads, writes, hist, window_len=window_len,
+                                   k_len=k_len, hi=hi, lo=lo)
+        interpret = False
     return sysmon_pass_pallas(reads, writes, hist, window_len=window_len,
                               k_len=k_len, hi=hi, lo=lo, block=block,
                               interpret=interpret)

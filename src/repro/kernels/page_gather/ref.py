@@ -1,5 +1,13 @@
 """Pure-jnp oracles for page gather/scatter and the fused int8 variants."""
+import jax
 import jax.numpy as jnp
+
+
+def int8_step():
+    """The quantizer's 127 as an opaque operand: XLA rewrites division by
+    a literal into multiplication by its inexact reciprocal, which would
+    break bit parity with the host-pool (numpy) quantizer."""
+    return jax.lax.optimization_barrier(jnp.float32(127.0))
 
 
 def page_gather_ref(pool, idx):
@@ -14,7 +22,8 @@ def _page_scale(pages):
     """Per-page int8 scale: max(absmax, 1e-8)/127, matching the host-pool
     quantizer (``core.tiers.HostPool.write_batch``) bit for bit."""
     axes = tuple(range(1, pages.ndim))
-    return jnp.maximum(jnp.max(jnp.abs(pages), axis=axes), 1e-8) / 127.0
+    return (jnp.maximum(jnp.max(jnp.abs(pages), axis=axes), 1e-8)
+            / int8_step())
 
 
 def _bcast(scale, ndim):

@@ -11,11 +11,10 @@ from .ssd_scan import ssd_scan_pallas
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 128,
-             interpret: bool | None = None):
+             interpret: bool = False):
     """x: [B, L, H, P]; dt: [B, L, H]; A: [H]; Bm/Cm: [B, L, N].
-    Pads L to a chunk multiple (identity steps: dt=0)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    Pads L to a chunk multiple (identity steps: dt=0).  Compiled for the
+    TPU; ``interpret=True`` (kernel-parity tests) runs the interpreter."""
     L0 = x.shape[1]
     pad = (-L0) % chunk
     if pad:

@@ -10,7 +10,7 @@ scatter-add over a histogram array:
 Same layout discipline as ``kernels/hotness_update``: a 1-D grid over
 blocked spans of the counter array, everything in int32 VPU lanes.  A
 scatter is race-prone across grid steps, so each step instead *owns* one
-counter block and reduces the full event list against it — a [block, k]
+counter block and reduces the full event list against it — a [k, block]
 compare/select/sum that reads the event arrays once per block and writes
 each counter exactly once (deterministic, bit-exact vs. the numpy
 oracle).  Event lists are short (one entry per page write in a pass), so
@@ -27,13 +27,11 @@ from jax.experimental import pallas as pl
 
 def _wear_kernel(ids_ref, amt_ref, wear_ref, out_ref, *, block: int):
     i = pl.program_id(0)
-    base = i * block
-    # counters owned by this grid step, as a [block, 1] column
-    slots = base + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
-    ids = ids_ref[...].astype(jnp.int32).reshape(1, -1)    # [1, k]
-    amt = amt_ref[...].astype(jnp.int32).reshape(1, -1)
-    hits = jnp.where(slots == ids, amt, 0)                 # [block, k]
-    out_ref[...] = wear_ref[...] + jnp.sum(hits, axis=1)
+    # events down the sublanes ([k, 1] columns), the counters owned by
+    # this grid step across the lanes ([1, block] row)
+    slots = i * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    hits = jnp.where(ids_ref[...] == slots, amt_ref[...], 0)   # [k, block]
+    out_ref[...] = wear_ref[...] + jnp.sum(hits, axis=0, keepdims=True)
 
 
 def wear_update_pallas(wear: jnp.ndarray, slot_ids: jnp.ndarray,
@@ -52,20 +50,19 @@ def wear_update_pallas(wear: jnp.ndarray, slot_ids: jnp.ndarray,
         # padded events point at a real slot but carry zero amount
         slot_ids = jnp.pad(slot_ids, (0, kpad))
         amount = jnp.pad(amount, (0, kpad))
-    nblocks = wear.shape[0] // block
+    n_full = wear.shape[0]
     kernel = functools.partial(_wear_kernel, block=block)
     kfull = slot_ids.shape[0]
+    espec = pl.BlockSpec((kfull, 1), lambda i: (0, 0))  # every step sees all ids
+    wspec = pl.BlockSpec((1, block), lambda i: (0, i))
     out = pl.pallas_call(
         kernel,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((kfull,), lambda i: (0,)),   # every step sees all ids
-            pl.BlockSpec((kfull,), lambda i: (0,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((wear.shape[0],), jnp.int32),
+        grid=(n_full // block,),
+        in_specs=[espec, espec, wspec],
+        out_specs=wspec,
+        out_shape=jax.ShapeDtypeStruct((1, n_full), jnp.int32),
         interpret=interpret,
-    )(slot_ids.astype(jnp.int32), amount.astype(jnp.int32),
-      wear.astype(jnp.int32))
-    return out[:n]
+    )(slot_ids.astype(jnp.int32).reshape(kfull, 1),
+      amount.astype(jnp.int32).reshape(kfull, 1),
+      wear.astype(jnp.int32).reshape(1, n_full))
+    return out[0, :n]

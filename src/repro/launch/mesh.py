@@ -7,18 +7,12 @@ set XLA_FLAGS before any jax initialization.
 from __future__ import annotations
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-except ImportError:  # pre-0.5 jax has no explicit axis types
-    AxisType = None
+from jax.sharding import AxisType
 
 from repro.parallel.sharding import MeshInfo
 
 
 def _mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

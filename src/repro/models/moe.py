@@ -24,13 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-else:  # pre-0.5 jax: experimental namespace, replication check spelled check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
 
 class MoEParams(NamedTuple):
     w_router: jnp.ndarray   # [d, E]
@@ -213,12 +206,12 @@ def moe_apply(x: jnp.ndarray, p: MoEParams, *, top_k: int,
             counts = jax.lax.psum(counts, dp_axes)
         return y, probs, idx, counts
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         wrapped,
         mesh=mesh,
         in_specs=(dp_spec, pspec),
         out_specs=out_specs,
-        **{_CHECK_KW: False},
+        check_vma=False,
     )
     y, probs, idx, counts = fn(xf, p)
     return y.reshape(B, S, d), (probs, idx, counts)

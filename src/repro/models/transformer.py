@@ -36,10 +36,6 @@ from . import layers, moe as moe_mod, ssm
 # parameter initialization
 # =============================================================================
 
-def _stack(trees: list):
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
-
-
 def _attn_dict(p: attn_mod.AttnParams) -> dict:
     return {k: v for k, v in p._asdict().items() if v is not None}
 
@@ -57,10 +53,18 @@ def mamba_spec_of(cfg: ArchConfig) -> ssm.MambaSpec:
                          chunk=cfg.chunk)
 
 
-def init_params(cfg: ArchConfig, key, dtype=jnp.float32) -> dict:
-    keys = iter(jax.random.split(key, 8 * cfg.n_layers + 16))
+def _keys_per_layer(cfg: ArchConfig) -> int:
+    if cfg.layout in ("mamba", "hybrid"):
+        return 1
+    if cfg.is_moe:
+        return 2
+    return 3 if cfg.mlp_kind == "gelu" else 4
+
+
+def _init_blocks(cfg: ArchConfig, keys, dtype):
+    """Builders for one layer's (and the shared block's) params, each
+    drawing its random leaves from ``keys`` in order."""
     d = cfg.d_model
-    vp = sh.pad_vocab(cfg.vocab)
 
     def one_attn():
         return _attn_dict(attn_mod.init_attn_params(
@@ -81,44 +85,64 @@ def init_params(cfg: ArchConfig, key, dtype=jnp.float32) -> dict:
                 "w_down": (jax.random.normal(next(keys), (d_ff, d))
                            * d_ff ** -0.5).astype(dtype)}
 
-    ln = lambda: (jnp.zeros((d,), dtype) if cfg.gemma_norm
+    ln = lambda: (jnp.zeros((d,), dtype) if cfg.gemma_norm  # noqa: E731
                   else jnp.ones((d,), dtype))
+    return one_attn, one_mlp, ln
 
-    layers_list = []
+
+def _init_layer(cfg: ArchConfig, layer_keys, dtype) -> dict:
+    """One layer's params from its ``_keys_per_layer(cfg)`` keys."""
+    keys = iter(layer_keys)
+    one_attn, one_mlp, ln = _init_blocks(cfg, keys, dtype)
     if cfg.layout in ("mamba", "hybrid"):
-        spec = mamba_spec_of(cfg)
-        for _ in range(cfg.n_layers):
-            layers_list.append({
-                "ln": ln(),
-                "mamba": ssm.init_mamba_params(next(keys), spec, dtype)._asdict(),
-            })
+        return {"ln": ln(), "mamba": ssm.init_mamba_params(
+            next(keys), mamba_spec_of(cfg), dtype)._asdict()}
+    lp: dict = {"ln1": ln(), "ln2": ln(), "attn": one_attn()}
+    if cfg.is_moe:
+        lp["moe"] = moe_mod.init_moe_params(
+            next(keys), cfg.d_model, cfg.n_experts,
+            cfg.expert_d_ff or cfg.d_ff, dtype)._asdict()
     else:
-        for _ in range(cfg.n_layers):
-            lp: dict = {"ln1": ln(), "ln2": ln(), "attn": one_attn()}
-            if cfg.is_moe:
-                lp["moe"] = moe_mod.init_moe_params(
-                    next(keys), d, cfg.n_experts, cfg.expert_d_ff or cfg.d_ff,
-                    dtype)._asdict()
-            else:
-                lp["mlp"] = one_mlp(cfg.d_ff)
-            if cfg.gemma_norm:
-                lp["ln1_post"] = ln()
-                lp["ln2_post"] = ln()
-            layers_list.append(lp)
+        lp["mlp"] = one_mlp(cfg.d_ff)
+    if cfg.gemma_norm:
+        lp["ln1_post"] = ln()
+        lp["ln2_post"] = ln()
+    return lp
 
-    params: dict = {"layers": _stack(layers_list), "final_norm": ln()}
+
+@partial(jax.jit, donate_argnums=(0,))
+def _put_layer(stack, layer, lp):
+    return jax.tree.map(lambda s, x: s.at[layer].set(x), stack, lp)
+
+
+def init_params(cfg: ArchConfig, key, dtype=jnp.float32) -> dict:
+    """Random params from ``key``.  The layer stack is allocated once and
+    filled one layer at a time (each layer drawn, then written into the
+    donated stack), so peak memory stays near one copy of the weights in
+    ``dtype`` — the full-width bf16 model fits one chip."""
+    L = cfg.n_layers
+    keys = jax.random.split(key, 8 * L + 16)
+    kpl = _keys_per_layer(cfg)
+    init_one = partial(_init_layer, cfg, dtype=dtype)
+    shapes = jax.eval_shape(init_one, keys[:kpl])
+    stack = jax.tree.map(lambda a: jnp.zeros((L, *a.shape), a.dtype), shapes)
+    for l in range(L):
+        stack = _put_layer(stack, l, init_one(keys[l * kpl:(l + 1) * kpl]))
+
+    rest = iter(keys[L * kpl:])
+    one_attn, one_mlp, ln = _init_blocks(cfg, rest, dtype)
+    d = cfg.d_model
+    params: dict = {"layers": stack, "final_norm": ln()}
     if cfg.layout == "hybrid":
         params["shared"] = {
             "ln1": ln(), "ln2": ln(),
             "attn": one_attn(), "mlp": one_mlp(cfg.d_ff),
         }
-    if cfg.tie_embeddings:
-        params["embed"] = (jax.random.normal(next(keys), (cfg.vocab, d))
-                           * d ** -0.5).astype(dtype)
-    else:
-        params["embed"] = (jax.random.normal(next(keys), (vp, d))
-                           * d ** -0.5).astype(dtype)
-        params["lm_head"] = (jax.random.normal(next(keys), (d, vp))
+    vp = cfg.vocab if cfg.tie_embeddings else sh.pad_vocab(cfg.vocab)
+    params["embed"] = (jax.random.normal(next(rest), (vp, d))
+                       * d ** -0.5).astype(dtype)
+    if not cfg.tie_embeddings:
+        params["lm_head"] = (jax.random.normal(next(rest), (d, vp))
                              * d ** -0.5).astype(dtype)
     return params
 
@@ -146,10 +170,14 @@ def embed_in(params: dict, cfg: ArchConfig, batch: dict,
 
 
 def logits_out(params: dict, cfg: ArchConfig, h: jnp.ndarray) -> jnp.ndarray:
+    """float32 logits whatever the weights' dtype (bf16 logits would round
+    away the margins greedy sampling decides on)."""
     if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", h, params["embed"])
+        logits = jnp.einsum("bsd,vd->bsv", h, params["embed"],
+                            preferred_element_type=jnp.float32)
     else:
-        logits = jnp.einsum("bsd,dv->bsv", h, params["lm_head"])
+        logits = jnp.einsum("bsd,dv->bsv", h, params["lm_head"],
+                            preferred_element_type=jnp.float32)
     vp = logits.shape[-1]
     if vp != cfg.vocab:  # mask padded vocab columns
         pad_mask = jnp.arange(vp) >= cfg.vocab

@@ -50,7 +50,7 @@ from repro.configs.base import ArchConfig
 from repro.core import sysmon as sysmon_mod
 from repro.core.hierarchy import MemoryHierarchy
 from repro.core.memos import MemosConfig, MemosManager
-from repro.core.tiers import NO_SLOT
+from repro.core.tiers import NO_SLOT, to_device_memory, to_pinned_host
 from repro.faults.errors import CapacityError, PageCorruptionError
 from repro.faults.injector import get_injector, note_recovered
 from repro.kernels.paged_attention import paged_attention, paged_attention_pages
@@ -116,11 +116,13 @@ class PagedServingEngine:
         self.cfg = cfg
         self.params = params
         self.scfg = scfg
+        # KV pages are stored in the parameters' dtype (bf16 weights ->
+        # bf16 pages; float32 smoke configs keep float32 pools)
         self.kv = PagedKVCache(PagedKVConfig(
             n_layers=cfg.n_layers, n_kv_heads=cfg.n_kv_heads,
             head_dim=cfg.head_dim, page_size=scfg.page_size,
             fast_slots=scfg.fast_slots, slow_slots=scfg.slow_slots,
-            hierarchy=scfg.hierarchy))
+            dtype=params["embed"].dtype, hierarchy=scfg.hierarchy))
         store = self.kv.store
         # dual-pool serving: when the deepest tier is a (lossless)
         # pinned-host pool, its pages are served and appended in place by
@@ -162,9 +164,10 @@ class PagedServingEngine:
         self.tokens_out = 0
         self.rid = 0
         self.last_logits = None     # final inner step's logits, on device
+        self.last_rows: list[int] = []  # request id of each last_logits row
         self._decode_fn = jax.jit(self._decode_batch, donate_argnums=(5,))
         self._decode_pinned_fn = jax.jit(self._decode_batch_pinned,
-                                         donate_argnums=(6, 7))
+                                         donate_argnums=(6,))
         self._fused_fns: dict[int, object] = {}
         self._fused_pinned_fns: dict[int, object] = {}
         self.prefill_runner = (PrefillRunner(self)
@@ -333,12 +336,15 @@ class PagedServingEngine:
 
     # -- jitted model compute ------------------------------------------------------
     def _decode_core(self, params, tokens, positions, block_tables,
-                     lengths, fast_pool):
+                     lengths, fast_pool, live):
         """One decode step for the batch: write the new token's K/V into
         the pool *before* attention (exact self-attention), run the layer
         stack through paged_attention.  tokens [B] i32; positions [B];
         block_tables [B,P] fast-slot ids; lengths [B] (incl. current
-        token).  Returns (logits [B,Vp], expert_counts|0, new fast_pool)."""
+        token); live [B] bool, False for the padding rows that fill the
+        dispatch to its fixed width (their K/V writes are dropped and
+        they count no experts).  Returns (logits [B,Vp],
+        expert_counts|0, new fast_pool)."""
         cfg = self.cfg
         page = self.scfg.page_size
         B = tokens.shape[0]
@@ -346,7 +352,8 @@ class PagedServingEngine:
         cos, sin = L.rope_angles(positions[:, None], cfg.head_dim,
                                  cfg.rope_theta)
         b_idx = jnp.arange(B)
-        slot = block_tables[b_idx, positions // page]
+        slot = jnp.where(live, block_tables[b_idx, positions // page],
+                         fast_pool.shape[0])
         off = positions % page
         counts_acc = (jnp.zeros((cfg.n_experts,), jnp.int32)
                       if cfg.is_moe else jnp.int32(0))
@@ -358,15 +365,16 @@ class PagedServingEngine:
             q, k, v = attn_mod.project_qkv(p, x, cos, sin)
             dtype = fast_pool.dtype
             fast_pool = fast_pool.at[slot, l, 0, off].set(
-                k[:, 0].astype(dtype))
+                k[:, 0].astype(dtype), mode="drop")
             fast_pool = fast_pool.at[slot, l, 1, off].set(
-                v[:, 0].astype(dtype))
+                v[:, 0].astype(dtype), mode="drop")
             out = paged_attention(q[:, 0], fast_pool[:, l, 0],
                                   fast_pool[:, l, 1], block_tables, lengths)
             out = jnp.einsum("bhk,hkd->bd", out.reshape(
                 B, cfg.n_heads, cfg.head_dim), p.wo)[:, None, :]
             h = h + out
-            h, counts, _ = T._ffn_block(lp, cfg, h, None)
+            h, counts, _ = T._ffn_block(lp, cfg, h, None,
+                                        valid=live[:, None])
             if cfg.is_moe and counts is not None:
                 counts_acc = counts_acc + counts
         h = L.rms_norm(h, params["final_norm"], eps=cfg.norm_eps,
@@ -375,16 +383,16 @@ class PagedServingEngine:
         return logits, counts_acc, fast_pool
 
     def _decode_batch(self, params, tokens, positions, block_tables,
-                      lengths, fast_pool):
+                      lengths, fast_pool, live):
         """Retained K=1 reference entry point (tokens [B,1]); sampling and
         SysMon charging stay on the host."""
         return self._decode_core(params, tokens[:, 0], positions,
-                                 block_tables, lengths, fast_pool)
+                                 block_tables, lengths, fast_pool, live)
 
     # -- dual-pool (pinned-host deepest tier) decode -----------------------------
     def _decode_core_pinned(self, params, tokens, positions, block_tables,
                             pool_sel, lengths, fast_pool, pinned_pool,
-                            remap):
+                            remap, live):
         """One decode step with the KV split across the tier-0 pool and
         the pinned-host pool: pages are attended wherever they live
         (per-page select after a dual gather) and the new token's K/V
@@ -401,7 +409,7 @@ class PagedServingEngine:
         whose tail lives in the other pool write through an out-of-range
         index dropped by the scatter (``mode="drop"``), so a numeric slot
         collision between the two pools can never clobber a real
-        write."""
+        write; padding rows (``live`` False) write to neither pool."""
         cfg = self.cfg
         page = self.scfg.page_size
         B = tokens.shape[0]
@@ -420,8 +428,9 @@ class PagedServingEngine:
         slot = block_tables[b_idx, tailcol]
         sel_tail = pool_sel[b_idx, tailcol] > 0
         off = positions % page
-        f_idx = jnp.where(sel_tail, n_fast, slot)   # OOB for pinned tails
-        p_idx = jnp.where(sel_tail, slot, n_pin)    # OOB for fast tails
+        # OOB (dropped) for pinned tails / fast tails / padding rows
+        f_idx = jnp.where(sel_tail | ~live, n_fast, slot)
+        p_idx = jnp.where(sel_tail & live, slot, n_pin)
         sel_pages = (pool_sel > 0)[:, :, None, None, None]
         counts_acc = (jnp.zeros((cfg.n_experts,), jnp.int32)
                       if cfg.is_moe else jnp.int32(0))
@@ -452,7 +461,8 @@ class PagedServingEngine:
             out = jnp.einsum("bhk,hkd->bd", out.reshape(
                 B, cfg.n_heads, cfg.head_dim), p.wo)[:, None, :]
             h = h + out
-            h, counts, _ = T._ffn_block(lp, cfg, h, None)
+            h, counts, _ = T._ffn_block(lp, cfg, h, None,
+                                        valid=live[:, None])
             if cfg.is_moe and counts is not None:
                 counts_acc = counts_acc + counts
         h = L.rms_norm(h, params["final_norm"], eps=cfg.norm_eps,
@@ -462,11 +472,12 @@ class PagedServingEngine:
 
     def _decode_batch_pinned(self, params, tokens, positions, block_tables,
                              pool_sel, lengths, fast_pool, pinned_pool,
-                             remap):
+                             remap, live):
         """Retained K=1 reference entry point for the dual-pool path."""
-        return self._decode_core_pinned(params, tokens[:, 0], positions,
-                                        block_tables, pool_sel, lengths,
-                                        fast_pool, pinned_pool, remap)
+        logits, counts, fast_pool, pinned_pool = self._decode_core_pinned(
+            params, tokens[:, 0], positions, block_tables, pool_sel, lengths,
+            fast_pool, to_device_memory(pinned_pool), remap, live)
+        return logits, counts, fast_pool, to_pinned_host(pinned_pool)
 
     @staticmethod
     def _advance_prompt(positions, prompt_buf, prompt_len, sampled, b_idx):
@@ -482,7 +493,7 @@ class PagedServingEngine:
 
     def _fused_decode(self, params, tokens, positions, prompt_buf,
                       prompt_len, page_tables, block_tables, sm_state,
-                      fast_pool, *, k_steps: int):
+                      fast_pool, live, *, k_steps: int):
         """K inner decode steps in one dispatch: a ``lax.scan`` carrying
         (tokens, positions, SysmonState, fast_pool, page-write counters).
         Greedy sampling, the SysMon read/write scatter-adds, and the
@@ -492,7 +503,8 @@ class PagedServingEngine:
         tokens/positions [B]; prompt_buf [B, Lp] padded prompt tokens;
         prompt_len [B]; page_tables [B, P] logical page ids (SysMon's
         id space); block_tables [B, P] fast-pool slots; sm_state and
-        fast_pool are donated loop state.
+        fast_pool are donated loop state; live [B] marks real rows (the
+        padding rows charge SysMon and the write counters nothing).
         """
         cfg = self.cfg
         page = self.scfg.page_size
@@ -507,7 +519,8 @@ class PagedServingEngine:
         def body(carry, _):
             tokens, positions, sm, pool, page_writes, counts_acc, _ = carry
             logits, counts, pool = self._decode_core(
-                params, tokens, positions, block_tables, positions + 1, pool)
+                params, tokens, positions, block_tables, positions + 1, pool,
+                live)
             # device-side greedy sampling feeds the next inner step
             sampled = jnp.argmax(logits[:, :cfg.vocab],
                                  axis=-1).astype(jnp.int32)
@@ -520,13 +533,13 @@ class PagedServingEngine:
             tailcol = positions // page
             sm = sysmon_mod.record(
                 sm, page_tables.reshape(-1), is_write=False,
-                valid=(col <= tailcol[:, None]).reshape(-1))
+                valid=((col <= tailcol[:, None]) & live[:, None]).reshape(-1))
             tails = page_tables[b_idx, tailcol]
-            sm = sysmon_mod.record(sm, tails, is_write=True)
+            sm = sysmon_mod.record(sm, tails, is_write=True, valid=live)
             # fast-tier version/write counters (the dirty bits optimistic
             # migration checks) accumulate on device, applied in bulk at
             # the dispatch boundary
-            page_writes = page_writes.at[tails].add(1)
+            page_writes = page_writes.at[tails].add(live.astype(jnp.int32))
             if cfg.is_moe:
                 counts_acc = counts_acc + counts
             return (nxt_tok, nxt_pos, sm, pool, page_writes, counts_acc,
@@ -553,7 +566,7 @@ class PagedServingEngine:
     def _fused_decode_pinned(self, params, tokens, positions, prompt_buf,
                              prompt_len, page_tables, block_tables, pool_sel,
                              sm_state, fast_pool, pinned_pool, wear, remap,
-                             gap, pending, *, k_steps: int,
+                             gap, pending, live, *, k_steps: int,
                              gap_interval: int):
         """The dual-pool fused dispatch: K inner steps with KV appends
         landing in either pool and the pinned tier's wear counters riding
@@ -577,12 +590,15 @@ class PagedServingEngine:
         #advances) back into the host trackers.  ``gap_interval`` 0
         disables in-dispatch leveling (untracked or unleveled pinned
         tiers); SysMon, sampling, and the page-write counters are
-        unchanged from the single-pool path."""
+        unchanged from the single-pool path.  The pinned pool enters the
+        program in host memory and is moved to device memory for the
+        scan; the updated pool is placed back in host memory on exit."""
         cfg = self.cfg
         page = self.scfg.page_size
         B, P = block_tables.shape
         b_idx = jnp.arange(B)
         col = jnp.arange(P, dtype=jnp.int32)[None, :]
+        pinned_pool = to_device_memory(pinned_pool)
         n_pin = pinned_pool.shape[0]
         vp = (params["embed"].shape[0] if cfg.tie_embeddings
               else params["lm_head"].shape[1])
@@ -607,7 +623,7 @@ class PagedServingEngine:
              page_writes, counts_acc, _) = carry
             logits, counts, fpool, ppool = self._decode_core_pinned(
                 params, tokens, positions, block_tables, pool_sel,
-                positions + 1, fpool, ppool, remap)
+                positions + 1, fpool, ppool, remap, live)
             sampled = jnp.argmax(logits[:, :cfg.vocab],
                                  axis=-1).astype(jnp.int32)
             nxt_tok, nxt_pos = self._advance_prompt(
@@ -615,15 +631,15 @@ class PagedServingEngine:
             tailcol = positions // page
             sm = sysmon_mod.record(
                 sm, page_tables.reshape(-1), is_write=False,
-                valid=(col <= tailcol[:, None]).reshape(-1))
+                valid=((col <= tailcol[:, None]) & live[:, None]).reshape(-1))
             tails = page_tables[b_idx, tailcol]
-            sm = sysmon_mod.record(sm, tails, is_write=True)
-            page_writes = page_writes.at[tails].add(1)
+            sm = sysmon_mod.record(sm, tails, is_write=True, valid=live)
+            page_writes = page_writes.at[tails].add(live.astype(jnp.int32))
             # pinned-tier wear: tails living in the pinned pool charge
             # their physical row — under the carried remap — on device
             # (amount 0 for fast tails)
             tail_slot = block_tables[b_idx, tailcol]
-            tail_pin = pool_sel[b_idx, tailcol]
+            tail_pin = pool_sel[b_idx, tailcol] * live.astype(jnp.int32)
             tail_phys = remap[jnp.clip(tail_slot, 0, n_pin - 1)]
             wear = wear_update(wear, tail_phys, amount=tail_pin)
             pin_w = pin_w + tail_pin.sum()
@@ -644,15 +660,16 @@ class PagedServingEngine:
             ppool, wear, remap, gap, pending, n_adv = jax.lax.while_loop(
                 lambda s: s[4] >= gap_interval, advance_gap,
                 (ppool, wear, remap, gap, pending, n_adv))
-        return (sampled, logits, sm, fpool, ppool, wear, remap, gap,
-                pending, n_adv, page_writes, counts)
+        return (sampled, logits, sm, fpool, to_pinned_host(ppool), wear,
+                remap, gap, pending, n_adv, page_writes, counts)
 
     def _get_fused_pinned(self, k: int):
         fn = self._fused_pinned_fns.get(k)
         if fn is None:
+            # the pinned pool is not donated (see core.tiers)
             fn = jax.jit(partial(self._fused_decode_pinned, k_steps=k,
                                  gap_interval=self._gap_interval),
-                         donate_argnums=(9, 10))   # fast_pool, pinned_pool
+                         donate_argnums=(9,))      # fast_pool
             self._fused_pinned_fns[k] = fn
         return fn
 
@@ -689,14 +706,16 @@ class PagedServingEngine:
         while k <= self.scfg.decode_block:
             ks.append(k)
             k *= 2
+        live = jnp.ones((B,), bool)
         for k in ks:
             args = (self.params, zi(B), zi(B), zi(B, P * page), zi(B),
                     zi(B, P), zi(B, P))
-            # pools are donated by the dispatch: hand each call its own
-            # dummy copy, never the live buffers
+            # the fast pool is donated by the dispatch: hand each call
+            # its own dummy copy, never the live buffer (the pinned pool
+            # is only read)
             jax.block_until_ready(
                 self._get_fused(k)(*args, sm,
-                                   jnp.zeros_like(store.fast_pool))[0])
+                                   jnp.zeros_like(store.fast_pool), live)[0])
             if self.pinned_tier is not None:
                 ppool = store.pools[self.pinned_tier]
                 # match the live dispatch's wear-array shape exactly: the
@@ -709,8 +728,8 @@ class PagedServingEngine:
                     self._get_fused_pinned(k)(
                         *args, zi(B, P), sm,
                         jnp.zeros_like(store.fast_pool),
-                        jnp.zeros_like(ppool.data), wear, remap,
-                        jnp.int32(0), jnp.int32(0))[0])
+                        ppool.data, wear, remap,
+                        jnp.int32(0), jnp.int32(0), live)[0])
         # prefill: AOT-compile every advertised (bucket, pool-variant)
         # dispatch — .lower().compile() against abstract shapes, so no
         # dummy pool copies are needed and serving never recompiles
@@ -814,7 +833,7 @@ class PagedServingEngine:
         store = self.kv.store
         page = self.scfg.page_size
         pt = self.pinned_tier
-        Pp = pr.n_table_pages(group.bucket)
+        Pp = pr.table_pages
         pages_rows = [r.pages for r in segs]
         if pt is None:
             page_tables, block_tables = self.kv.fill_tables(pages_rows, Pp)
@@ -864,6 +883,7 @@ class PagedServingEngine:
             first = np.asarray(first_d)
         dt = time.perf_counter() - t0
         self.last_logits = seg_logits
+        self.last_rows = [r.rid for r in segs]
         reg = obs.get_registry()
         reg.histogram("serving.prefill_latency_s",
                       "wall time of one packed prefill dispatch").observe(dt)
@@ -1064,6 +1084,23 @@ class PagedServingEngine:
                 pool_sel = None
                 wear_tr = None
 
+        # the dispatch always runs max_batch rows: padding rows (live
+        # False) keep one program per K however far the batch drains, so
+        # warmup covers every shape serving emits
+        Bp = max(self.scfg.max_batch, B)
+
+        def rows(a):
+            pad = [(0, Bp - B)] + [(0, 0)] * (a.ndim - 1)
+            return jnp.asarray(np.pad(a, pad))
+
+        live = jnp.asarray(np.arange(Bp) < B)
+        d_tokens, d_pos = rows(tokens), rows(positions)
+        d_pages, d_blocks = rows(page_tables), rows(block_tables)
+        d_sel = rows(pool_sel) if pool_sel is not None else None
+        prompt_buf = np.zeros((Bp, P * page), np.int32)
+        for i, r in enumerate(active):
+            prompt_buf[i, :len(r.prompt)] = r.prompt
+
         dispatch_path = (("reference" if self.scfg.reference else "fused")
                          + ("+pinned" if pt is not None else ""))
         # wall clock measured independently of tracing — the latency
@@ -1074,9 +1111,9 @@ class PagedServingEngine:
             if self.scfg.reference and pt is None:
                 # -- retained K=1 reference path (parity oracle / baseline)
                 logits, ecounts, store.fast_pool = self._decode_fn(
-                    self.params, jnp.asarray(tokens[:, None]),
-                    jnp.asarray(positions), jnp.asarray(block_tables),
-                    jnp.asarray(positions + 1), store.fast_pool)
+                    self.params, d_tokens[:, None], d_pos, d_blocks,
+                    d_pos + 1, store.fast_pool, live)
+                logits = logits[:B]
                 # host-side argmax sampling: one transfer per token
                 sampled = np.asarray(
                     jnp.argmax(logits[:, :self.cfg.vocab], axis=-1),
@@ -1102,10 +1139,10 @@ class PagedServingEngine:
                              else jnp.arange(n_pin, dtype=jnp.int32))
                 logits, ecounts, store.fast_pool, ppool.data = \
                     self._decode_pinned_fn(
-                        self.params, jnp.asarray(tokens[:, None]),
-                        jnp.asarray(positions), jnp.asarray(block_tables),
-                        jnp.asarray(pool_sel), jnp.asarray(positions + 1),
-                        store.fast_pool, ppool.data, remap_arr)
+                        self.params, d_tokens[:, None], d_pos, d_blocks,
+                        d_sel, d_pos + 1, store.fast_pool, ppool.data,
+                        remap_arr, live)
+                logits = logits[:B]
                 sampled = np.asarray(
                     jnp.argmax(logits[:, :self.cfg.vocab], axis=-1),
                     np.int32)[None, :]
@@ -1133,27 +1170,21 @@ class PagedServingEngine:
                 self.last_logits = logits
             elif pt is None:
                 # -- fused K-step dispatch ---------------------------------
-                prompt_buf = np.zeros((B, P * page), np.int32)
-                for i, r in enumerate(active):
-                    prompt_buf[i, :len(r.prompt)] = r.prompt
                 fn = self._get_fused(k)
                 (sampled_d, logits, self.sysmon, store.fast_pool,
                  page_writes_d, ecounts) = fn(
-                    self.params, jnp.asarray(tokens), jnp.asarray(positions),
-                    jnp.asarray(prompt_buf), jnp.asarray(prompt_lens),
-                    jnp.asarray(page_tables), jnp.asarray(block_tables),
-                    self.sysmon, store.fast_pool)
-                sampled = np.asarray(sampled_d)  # one transfer per K tokens
+                    self.params, d_tokens, d_pos, jnp.asarray(prompt_buf),
+                    rows(prompt_lens), d_pages, d_blocks, self.sysmon,
+                    store.fast_pool, live)
+                # one transfer per K tokens
+                sampled = np.asarray(sampled_d)[:, :B]
                 page_writes = np.asarray(page_writes_d)
-                self.last_logits = logits
+                self.last_logits = logits[:B]
             else:
                 # -- fused K-step dual-pool dispatch: slow-tier KV appends
                 # and the wear_update scatter-add ride the same scan -------
                 ppool = store.pools[pt]
                 n_pin_rows = ppool.data.shape[0]
-                prompt_buf = np.zeros((B, P * page), np.int32)
-                for i, r in enumerate(active):
-                    prompt_buf[i, :len(r.prompt)] = r.prompt
                 wear_arr = (wear_tr.state.wear if wear_tr is not None
                             else jnp.zeros((1,), jnp.int32))
                 remap_arr = (wear_tr.state.remap if wear_tr is not None
@@ -1166,12 +1197,11 @@ class PagedServingEngine:
                 (sampled_d, logits, self.sysmon, store.fast_pool, ppool.data,
                  wear_out, remap_out, gap_out, pending_out, n_adv_out,
                  page_writes_d, ecounts) = fn(
-                    self.params, jnp.asarray(tokens), jnp.asarray(positions),
-                    jnp.asarray(prompt_buf), jnp.asarray(prompt_lens),
-                    jnp.asarray(page_tables), jnp.asarray(block_tables),
-                    jnp.asarray(pool_sel), self.sysmon, store.fast_pool,
-                    ppool.data, wear_arr, remap_arr, gap0, pending0)
-                sampled = np.asarray(sampled_d)
+                    self.params, d_tokens, d_pos, jnp.asarray(prompt_buf),
+                    rows(prompt_lens), d_pages, d_blocks, d_sel, self.sysmon,
+                    store.fast_pool, ppool.data, wear_arr, remap_arr, gap0,
+                    pending0, live)
+                sampled = np.asarray(sampled_d)[:, :B]
                 page_writes = np.asarray(page_writes_d)
                 if wear_tr is not None:
                     n_pin_w = int(page_writes[store.tier == pt].sum())
@@ -1188,8 +1218,9 @@ class PagedServingEngine:
                             wear_tr.adopt_scan_remap(remap_out)
                         if lv is not None:
                             lv.adopt_scan_advances(n_adv, int(pending_out))
-                self.last_logits = logits
+                self.last_logits = logits[:B]
         dispatch_dt = time.perf_counter() - t_disp0
+        self.last_rows = [r.rid for r in active]
         self._publish_dispatch_metrics(dispatch_dt, k, B)
 
         if self.expert_counts is not None:

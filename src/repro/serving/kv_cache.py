@@ -190,8 +190,7 @@ class PagedKVCache:
             pool = self.store.pools[t]
             assert not pool.quantized, \
                 "token-granular appends need a lossless pinned pool"
-            pool.data = pool.data.at[phys, :, :, offset].set(
-                layer_kv.astype(pool.data.dtype))
+            pool.write_token(phys, offset, layer_kv)
             self.store._account_host_writes(t, np.asarray([phys]))
             self.store.integrity.record(self.store, t, [slot])
         else:

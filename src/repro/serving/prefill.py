@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.tiers import to_device_memory, to_pinned_host
 from repro.kernels.paged_attention import (paged_attention_prefill,
                                            paged_attention_prefill_pages)
 from repro.models import attention as attn_mod
@@ -183,12 +184,14 @@ class PrefillRunner:
     def buckets(self) -> list[int]:
         return bucket_list(self.min_bucket, self.max_bucket)
 
-    def n_table_pages(self, bucket: int) -> int:
-        """Per-row block-table width: just the pages covering the bucket
-        (not ``max_pages_per_seq`` — the attention gather materializes
-        [L, P, page] keys, so the table stays as narrow as possible)."""
-        page = self.eng.scfg.page_size
-        return (bucket + page - 1) // page
+    @property
+    def table_pages(self) -> int:
+        """Per-row block-table width: the decode dispatch's
+        ``max_pages_per_seq`` whatever the bucket, so a prefilled
+        position's attention reduces over exactly the columns its
+        decode-step twin would (a narrower table changes the softmax
+        summation order, and with it the low bits)."""
+        return self.eng.scfg.max_pages_per_seq
 
     # -- core compute (mirrors engine._decode_core op-for-op) -----------------
     def _core_plain(self, params, tokens, local_pos, row_tables, lengths,
@@ -246,9 +249,12 @@ class PrefillRunner:
         position's K/V scatters into whichever pool owns its page, with
         the other pool's index driven out of range and dropped.  Wear
         and integrity for the pinned writes are charged at the boundary
-        by the engine (host-side, same totals as per-token charging)."""
+        by the engine (host-side, same totals as per-token charging).
+        The pinned pool is moved into device memory for the dispatch and
+        placed back in host memory on exit."""
         cfg = self.eng.cfg
         Lb = tokens.shape[0]
+        pinned_pool = to_device_memory(pinned_pool)
         n_fast = fast_pool.shape[0]
         n_pin = pinned_pool.shape[0]
         row_tables = jnp.where(
@@ -301,7 +307,8 @@ class PrefillRunner:
         seg_logits = logits[seg_last]
         first = jnp.argmax(seg_logits[:, :cfg.vocab],
                            axis=-1).astype(jnp.int32)
-        return first, seg_logits, counts_acc, fast_pool, pinned_pool
+        return (first, seg_logits, counts_acc, fast_pool,
+                to_pinned_host(pinned_pool))
 
     # -- AOT compilation ------------------------------------------------------
     def _abstract_params(self):
@@ -311,7 +318,7 @@ class PrefillRunner:
 
     def _compile_plain(self, bucket: int):
         store = self.eng.kv.store
-        Pp = self.n_table_pages(bucket)
+        Pp = self.table_pages
         S = self.max_segments
         i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
         fn = jax.jit(self._core_plain, donate_argnums=(8,))
@@ -329,17 +336,19 @@ class PrefillRunner:
         store = eng.kv.store
         ppool = store.pools[eng.pinned_tier]
         n_pin = ppool.data.shape[0]
-        Pp = self.n_table_pages(bucket)
+        Pp = self.table_pages
         S = self.max_segments
         i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-        fn = jax.jit(self._core_pinned, donate_argnums=(10, 11))
+        # the pinned pool is not donated (see core.tiers)
+        fn = jax.jit(self._core_pinned, donate_argnums=(10,))
         compiled = fn.lower(
             self._abstract_params(), i32(bucket), i32(bucket),
             i32(bucket, Pp), i32(bucket, Pp), i32(bucket), i32(bucket),
             i32(bucket), i32(bucket), i32(S),
             jax.ShapeDtypeStruct(store.fast_pool.shape,
                                  store.fast_pool.dtype),
-            jax.ShapeDtypeStruct(ppool.data.shape, ppool.data.dtype),
+            jax.ShapeDtypeStruct(ppool.data.shape, ppool.data.dtype,
+                                 sharding=ppool.data.sharding),
             i32(n_pin)).compile()
         self.n_compiles += 1
         self._pinned[bucket] = compiled
@@ -368,7 +377,7 @@ class PrefillRunner:
         eng = self.eng
         page = eng.scfg.page_size
         Lb = group.bucket
-        Pp = self.n_table_pages(Lb)
+        Pp = self.table_pages
         S = self.max_segments
         n_fast = eng.kv.store.fast_pool.shape[0]
         tokens = np.zeros(Lb, np.int32)

@@ -19,14 +19,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
 
-
-def _mesh_context(mesh):
-    """jax.set_mesh where available; on older jax the Mesh object itself is
-    the context manager that installs the ambient mesh."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
 from repro.configs import get_arch, smoke  # noqa: E402
 from repro.launch.mesh import make_debug_mesh, make_mesh_info  # noqa: E402
 from repro.launch.train import make_train_step  # noqa: E402
@@ -63,7 +55,7 @@ def check(arch_id: str, tweak=None, tol=5e-3):
     p_ref, o_ref, m_ref = ref_step(params, opt, batch)
 
     # sharded
-    with _mesh_context(mesh):
+    with jax.set_mesh(mesh):
         psh = jax.tree.map(lambda s: NamedSharding(mesh, s),
                            sh.param_specs(cfg, mi),
                            is_leaf=lambda x: isinstance(x, P))

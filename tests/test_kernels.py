@@ -216,3 +216,21 @@ def test_touch_update_kernel(n, k):
     want = touch_update_ref(n, np.asarray(ids), True)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), w)
+
+
+# --- page checksum -----------------------------------------------------------------
+
+@pytest.mark.parametrize("n_slots,k,shape", [(16, 4, (8, 4)), (8, 8, (16,)),
+                                             (12, 5, (3, 2, 8, 128))])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
+def test_page_checksum_kernel_parity(n_slots, k, shape, dtype):
+    """Chunked Pallas checksum (interpret mode) vs the numpy oracle over
+    the stored bits, bit-exact (uint32 wraparound included)."""
+    from repro.kernels.page_checksum import checksum_np, page_checksum
+    rng = np.random.RandomState(k)
+    raw = rng.randint(-100, 100, (n_slots, *shape))
+    pool = jnp.asarray(raw).astype(dtype)
+    idx = jnp.asarray(rng.permutation(n_slots)[:k], jnp.int32)
+    got = page_checksum(pool, idx, interpret=True)
+    want = checksum_np(np.asarray(pool)[np.asarray(idx)])
+    np.testing.assert_array_equal(np.asarray(got), want)
