@@ -11,7 +11,9 @@ for the repro, in two halves sharing one process-wide home:
     covers the serving dispatch boundaries (admit / provision / dispatch
     / retire), the async memos snapshot -> plan -> commit phases (plan
     spans land on the worker thread), batched migration per (src, dst)
-    tier group, and Start-Gap adoption.
+    tier group, and Start-Gap adoption.  While enabled, each span is also
+    a ``jax.profiler.TraceAnnotation``, so a profiler trace shows the
+    spans on the device ops' clock.
   * **metrics** (``obs/metrics.py``) — a registry of counters, gauges,
     and log-bucketed histograms that MemosManager, TierStore, and
     PagedServingEngine publish into at pass/dispatch boundaries:
@@ -23,7 +25,7 @@ for the repro, in two halves sharing one process-wide home:
     to a jitted K-token decode.
 
 Exporters (``obs/export.py``): Chrome trace-event JSON (chrome://tracing
-/ Perfetto), JSONL, and Prometheus-style text.
+/ Perfetto) and Prometheus-style text.
 
 Usage::
 
@@ -50,7 +52,7 @@ from .trace import NULL_SPAN, SpanEvent, Tracer
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "SpanEvent",
     "Tracer", "NULL_SPAN", "configure", "get_registry", "get_tracer",
-    "instant", "reset", "span", "tracing_enabled", "export",
+    "reset", "span", "tracing_enabled", "export",
 ]
 
 _tracer = Tracer(enabled=False)
@@ -87,12 +89,6 @@ def span(name: str, **attrs):
     if not t.enabled:
         return NULL_SPAN
     return t.span(name, **attrs)
-
-
-def instant(name: str, **attrs) -> None:
-    t = _tracer
-    if t.enabled:
-        t.instant(name, **attrs)
 
 
 def reset() -> None:

@@ -1,6 +1,6 @@
 """Exporters for the ``repro.obs`` tracer and metrics registry.
 
-Three formats:
+Two formats:
 
   * **Chrome trace-event JSON** (``chrome_trace`` / ``write_chrome_trace``)
     — load the file in chrome://tracing or https://ui.perfetto.dev to see
@@ -9,8 +9,6 @@ Three formats:
     worker track directly under the main thread's next ``serve.dispatch``
     span — the overlap the async pipeline exists to create, visible
     instead of inferred.
-  * **JSONL** (``to_jsonl`` / ``write_jsonl``) — one event object per
-    line, for ad-hoc grepping/pandas.
   * **Prometheus-style text** (``prometheus_text`` / ``write_prometheus``)
     — the metrics registry as ``# TYPE`` blocks; histograms emit
     cumulative ``_bucket{le=...}`` series plus ``_sum`` / ``_count``.
@@ -43,13 +41,9 @@ def chrome_trace(tracer: Tracer) -> dict:
         out.append({"ph": "M", "name": "thread_name", "pid": PID,
                     "tid": tid, "args": {"name": name}})
     for e in events:
-        ev = {"name": e.name, "ph": e.ph, "ts": (e.ts_ns - t0) / 1e3,
-              "pid": PID, "tid": e.tid, "args": _json_attrs(e.attrs)}
-        if e.ph == "X":
-            ev["dur"] = e.dur_ns / 1e3
-        else:              # instant events need a scope
-            ev["s"] = "t"
-        out.append(ev)
+        out.append({"name": e.name, "ph": e.ph, "ts": (e.ts_ns - t0) / 1e3,
+                    "dur": e.dur_ns / 1e3, "pid": PID, "tid": e.tid,
+                    "args": _json_attrs(e.attrs)})
     return {"traceEvents": out, "displayTimeUnit": "ms",
             "otherData": {"dropped_events": tracer.n_dropped}}
 
@@ -58,24 +52,6 @@ def write_chrome_trace(path: str | Path, tracer: Tracer) -> Path:
     path = Path(path)
     path.parent.mkdir(parents=True, exist_ok=True)
     path.write_text(json.dumps(chrome_trace(tracer), indent=1))
-    return path
-
-
-def to_jsonl(tracer: Tracer) -> str:
-    lines = []
-    for e in tracer.events():
-        lines.append(json.dumps({
-            "name": e.name, "ph": e.ph, "ts_ns": e.ts_ns,
-            "dur_ns": e.dur_ns, "tid": e.tid,
-            "thread": tracer.thread_names.get(e.tid, ""),
-            "args": _json_attrs(e.attrs)}))
-    return "\n".join(lines) + ("\n" if lines else "")
-
-
-def write_jsonl(path: str | Path, tracer: Tracer) -> Path:
-    path = Path(path)
-    path.parent.mkdir(parents=True, exist_ok=True)
-    path.write_text(to_jsonl(tracer))
     return path
 
 

@@ -31,6 +31,13 @@ Span nesting needs no explicit parent pointers: within one thread,
 context-manager discipline guarantees child spans are fully contained in
 their parent's [start, start+dur) interval, which is exactly the nesting
 model Chrome trace "X" (complete) events use.
+
+While tracing is enabled, every span also enters a
+``jax.profiler.TraceAnnotation`` of the same name, its attributes as the
+annotation's metadata, so a profiler trace taken meanwhile holds the
+program's spans on its host plane, on the same clock as the device ops.
+While no profiler trace is running a span makes no annotation, at the
+cost of one native check.
 """
 from __future__ import annotations
 
@@ -40,12 +47,12 @@ from typing import NamedTuple
 
 
 class SpanEvent(NamedTuple):
-    """One completed span (ph="X") or instant marker (ph="i")."""
+    """One completed span (Chrome phase "X")."""
 
     name: str
-    ph: str            # "X" complete span | "i" instant event
+    ph: str            # always "X", a complete span
     ts_ns: int         # monotonic start time
-    dur_ns: int        # 0 for instants
+    dur_ns: int
     tid: int           # OS thread ident of the recording thread
     attrs: dict | None
 
@@ -69,11 +76,39 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    """A live span: times itself between ``__enter__`` and ``__exit__``
-    and records one event on exit."""
+_annotation_cls = None      # jax.profiler.TraceAnnotation, on first use
 
-    __slots__ = ("_tracer", "name", "attrs", "t0")
+
+def _annotation(name: str, attrs: dict | None):
+    """A profiler annotation called ``name``, its metadata the span's
+    attributes, or None while no profiler trace is running;
+    ``jax.profiler`` is imported by the first enabled span."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+        _annotation_cls = TraceAnnotation
+    if not _annotation_cls.is_enabled():
+        return None
+    if not attrs:
+        return _annotation_cls(name)
+    return _annotation_cls(name, **_annotation_meta(attrs))
+
+
+def _annotation_meta(attrs: dict) -> dict:
+    """Span attributes as profiler-annotation metadata: numbers and
+    strings as they are, a list or tuple joined by ``;`` (the profiler
+    splits its metadata at commas), anything else as its ``str``."""
+    return {k: (v if isinstance(v, (int, float, str))
+                else ";".join(map(str, v)) if isinstance(v, (list, tuple))
+                else str(v)) for k, v in attrs.items()}
+
+
+class _Span:
+    """A live span: times itself between ``__enter__`` and ``__exit__``,
+    inside a profiler annotation of the same name, and records one event
+    on exit."""
+
+    __slots__ = ("_tracer", "name", "attrs", "t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict | None):
         self._tracer = tracer
@@ -87,15 +122,21 @@ class _Span:
             self.attrs = attrs
         else:
             self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**_annotation_meta(attrs))
 
     def __enter__(self) -> "_Span":
+        self._ann = _annotation(self.name, self.attrs)
+        if self._ann is not None:
+            self._ann.__enter__()
         self.t0 = time.monotonic_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.monotonic_ns()
-        self._tracer._record(self.name, "X", self.t0, t1 - self.t0,
-                             self.attrs)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._tracer._record(self.name, self.t0, t1 - self.t0, self.attrs)
         return False
 
 
@@ -119,18 +160,12 @@ class Tracer:
             return NULL_SPAN
         return _Span(self, name, attrs or None)
 
-    def instant(self, name: str, **attrs) -> None:
-        """Record a zero-duration marker (Chrome "i" event)."""
-        if not self.enabled:
-            return
-        self._record(name, "i", time.monotonic_ns(), 0, attrs or None)
-
-    def _record(self, name: str, ph: str, ts_ns: int, dur_ns: int,
+    def _record(self, name: str, ts_ns: int, dur_ns: int,
                 attrs: dict | None) -> None:
         tid = threading.get_ident()
         if tid not in self._thread_names:
             self._thread_names[tid] = threading.current_thread().name
-        ev = SpanEvent(name, ph, ts_ns, dur_ns, tid, attrs)
+        ev = SpanEvent(name, "X", ts_ns, dur_ns, tid, attrs)
         with self._lock:
             self._buf[self._n % self.capacity] = ev
             self._n += 1

@@ -359,27 +359,33 @@ class PagedServingEngine:
                       if cfg.is_moe else jnp.int32(0))
         for l in range(cfg.n_layers):
             lp = T._tree_slice(params["layers"], l)
-            x = L.rms_norm(h, lp["ln1"], eps=cfg.norm_eps,
-                           gemma_style=cfg.gemma_norm)
-            p = T._attn_from_dict(lp["attn"])
-            q, k, v = attn_mod.project_qkv(p, x, cos, sin)
-            dtype = fast_pool.dtype
-            fast_pool = fast_pool.at[slot, l, 0, off].set(
-                k[:, 0].astype(dtype), mode="drop")
-            fast_pool = fast_pool.at[slot, l, 1, off].set(
-                v[:, 0].astype(dtype), mode="drop")
-            out = paged_attention(q[:, 0], fast_pool[:, l, 0],
-                                  fast_pool[:, l, 1], block_tables, lengths)
-            out = jnp.einsum("bhk,hkd->bd", out.reshape(
-                B, cfg.n_heads, cfg.head_dim), p.wo)[:, None, :]
-            h = h + out
-            h, counts, _ = T._ffn_block(lp, cfg, h, None,
-                                        valid=live[:, None])
+            with jax.named_scope("attention"):
+                x = L.rms_norm(h, lp["ln1"], eps=cfg.norm_eps,
+                               gemma_style=cfg.gemma_norm)
+                p = T._attn_from_dict(lp["attn"])
+                q, k, v = attn_mod.project_qkv(p, x, cos, sin)
+            with jax.named_scope("kv_append"):
+                dtype = fast_pool.dtype
+                fast_pool = fast_pool.at[slot, l, 0, off].set(
+                    k[:, 0].astype(dtype), mode="drop")
+                fast_pool = fast_pool.at[slot, l, 1, off].set(
+                    v[:, 0].astype(dtype), mode="drop")
+            with jax.named_scope("attention"):
+                out = paged_attention(q[:, 0], fast_pool[:, l, 0],
+                                      fast_pool[:, l, 1], block_tables,
+                                      lengths)
+                out = jnp.einsum("bhk,hkd->bd", out.reshape(
+                    B, cfg.n_heads, cfg.head_dim), p.wo)[:, None, :]
+                h = h + out
+            with jax.named_scope("mlp"):
+                h, counts, _ = T._ffn_block(lp, cfg, h, None,
+                                            valid=live[:, None])
             if cfg.is_moe and counts is not None:
                 counts_acc = counts_acc + counts
-        h = L.rms_norm(h, params["final_norm"], eps=cfg.norm_eps,
-                       gemma_style=cfg.gemma_norm)
-        logits = T.logits_out(params, cfg, h)[:, 0]
+        with jax.named_scope("head"):
+            h = L.rms_norm(h, params["final_norm"], eps=cfg.norm_eps,
+                           gemma_style=cfg.gemma_norm)
+            logits = T.logits_out(params, cfg, h)[:, 0]
         return logits, counts_acc, fast_pool
 
     def _decode_batch(self, params, tokens, positions, block_tables,
@@ -436,38 +442,44 @@ class PagedServingEngine:
                       if cfg.is_moe else jnp.int32(0))
         for l in range(cfg.n_layers):
             lp = T._tree_slice(params["layers"], l)
-            x = L.rms_norm(h, lp["ln1"], eps=cfg.norm_eps,
-                           gemma_style=cfg.gemma_norm)
-            p = T._attn_from_dict(lp["attn"])
-            q, k, v = attn_mod.project_qkv(p, x, cos, sin)
-            fd, pd = fast_pool.dtype, pinned_pool.dtype
-            fast_pool = fast_pool.at[f_idx, l, 0, off].set(
-                k[:, 0].astype(fd), mode="drop")
-            fast_pool = fast_pool.at[f_idx, l, 1, off].set(
-                v[:, 0].astype(fd), mode="drop")
-            pinned_pool = pinned_pool.at[p_idx, l, 0, off].set(
-                k[:, 0].astype(pd), mode="drop")
-            pinned_pool = pinned_pool.at[p_idx, l, 1, off].set(
-                v[:, 0].astype(pd), mode="drop")
-            # dual gather + per-page select (out-of-range slots clamp and
-            # are discarded by the select)
-            k_pages = jnp.where(sel_pages,
-                                pinned_pool[block_tables, l, 0].astype(fd),
-                                fast_pool[block_tables, l, 0])
-            v_pages = jnp.where(sel_pages,
-                                pinned_pool[block_tables, l, 1].astype(fd),
-                                fast_pool[block_tables, l, 1])
-            out = paged_attention_pages(q[:, 0], k_pages, v_pages, lengths)
-            out = jnp.einsum("bhk,hkd->bd", out.reshape(
-                B, cfg.n_heads, cfg.head_dim), p.wo)[:, None, :]
-            h = h + out
-            h, counts, _ = T._ffn_block(lp, cfg, h, None,
-                                        valid=live[:, None])
+            with jax.named_scope("attention"):
+                x = L.rms_norm(h, lp["ln1"], eps=cfg.norm_eps,
+                               gemma_style=cfg.gemma_norm)
+                p = T._attn_from_dict(lp["attn"])
+                q, k, v = attn_mod.project_qkv(p, x, cos, sin)
+            with jax.named_scope("kv_append"):
+                fd, pd = fast_pool.dtype, pinned_pool.dtype
+                fast_pool = fast_pool.at[f_idx, l, 0, off].set(
+                    k[:, 0].astype(fd), mode="drop")
+                fast_pool = fast_pool.at[f_idx, l, 1, off].set(
+                    v[:, 0].astype(fd), mode="drop")
+                pinned_pool = pinned_pool.at[p_idx, l, 0, off].set(
+                    k[:, 0].astype(pd), mode="drop")
+                pinned_pool = pinned_pool.at[p_idx, l, 1, off].set(
+                    v[:, 0].astype(pd), mode="drop")
+            with jax.named_scope("attention"):
+                # dual gather + per-page select (out-of-range slots clamp
+                # and are discarded by the select)
+                k_pages = jnp.where(
+                    sel_pages, pinned_pool[block_tables, l, 0].astype(fd),
+                    fast_pool[block_tables, l, 0])
+                v_pages = jnp.where(
+                    sel_pages, pinned_pool[block_tables, l, 1].astype(fd),
+                    fast_pool[block_tables, l, 1])
+                out = paged_attention_pages(q[:, 0], k_pages, v_pages,
+                                            lengths)
+                out = jnp.einsum("bhk,hkd->bd", out.reshape(
+                    B, cfg.n_heads, cfg.head_dim), p.wo)[:, None, :]
+                h = h + out
+            with jax.named_scope("mlp"):
+                h, counts, _ = T._ffn_block(lp, cfg, h, None,
+                                            valid=live[:, None])
             if cfg.is_moe and counts is not None:
                 counts_acc = counts_acc + counts
-        h = L.rms_norm(h, params["final_norm"], eps=cfg.norm_eps,
-                       gemma_style=cfg.gemma_norm)
-        logits = T.logits_out(params, cfg, h)[:, 0]
+        with jax.named_scope("head"):
+            h = L.rms_norm(h, params["final_norm"], eps=cfg.norm_eps,
+                           gemma_style=cfg.gemma_norm)
+            logits = T.logits_out(params, cfg, h)[:, 0]
         return logits, counts_acc, fast_pool, pinned_pool
 
     def _decode_batch_pinned(self, params, tokens, positions, block_tables,
@@ -522,24 +534,29 @@ class PagedServingEngine:
                 params, tokens, positions, block_tables, positions + 1, pool,
                 live)
             # device-side greedy sampling feeds the next inner step
-            sampled = jnp.argmax(logits[:, :cfg.vocab],
-                                 axis=-1).astype(jnp.int32)
+            with jax.named_scope("head"):
+                sampled = jnp.argmax(logits[:, :cfg.vocab],
+                                     axis=-1).astype(jnp.int32)
             nxt_tok, nxt_pos = self._advance_prompt(
                 positions, prompt_buf, prompt_len, sampled, b_idx)
-            # SysMon: the exact access stream — one read sampling over the
-            # block-table prefix covering the current position, one write
-            # sampling on the tail page (same two-sampling cadence as the
-            # reference path, so pass counters are bit-comparable)
-            tailcol = positions // page
-            sm = sysmon_mod.record(
-                sm, page_tables.reshape(-1), is_write=False,
-                valid=((col <= tailcol[:, None]) & live[:, None]).reshape(-1))
-            tails = page_tables[b_idx, tailcol]
-            sm = sysmon_mod.record(sm, tails, is_write=True, valid=live)
-            # fast-tier version/write counters (the dirty bits optimistic
-            # migration checks) accumulate on device, applied in bulk at
-            # the dispatch boundary
-            page_writes = page_writes.at[tails].add(live.astype(jnp.int32))
+            with jax.named_scope("sysmon"):
+                # SysMon: the exact access stream — one read sampling over
+                # the block-table prefix covering the current position,
+                # one write sampling on the tail page (same two-sampling
+                # cadence as the reference path, so pass counters are
+                # bit-comparable)
+                tailcol = positions // page
+                sm = sysmon_mod.record(
+                    sm, page_tables.reshape(-1), is_write=False,
+                    valid=((col <= tailcol[:, None])
+                           & live[:, None]).reshape(-1))
+                tails = page_tables[b_idx, tailcol]
+                sm = sysmon_mod.record(sm, tails, is_write=True, valid=live)
+                # fast-tier version/write counters (the dirty bits
+                # optimistic migration checks) accumulate on device,
+                # applied in bulk at the dispatch boundary
+                page_writes = page_writes.at[tails].add(
+                    live.astype(jnp.int32))
             if cfg.is_moe:
                 counts_acc = counts_acc + counts
             return (nxt_tok, nxt_pos, sm, pool, page_writes, counts_acc,
@@ -558,8 +575,11 @@ class PagedServingEngine:
             # only the pool is donated: SysmonState fields routinely alias
             # one shared zeros buffer (init/end_pass), which XLA rejects
             # as a double donation — and the state is tiny anyway
-            fn = jax.jit(partial(self._fused_decode, k_steps=k),
-                         donate_argnums=(8,))       # fast_pool
+            step = partial(self._fused_decode, k_steps=k)
+            # the program's name in profiler traces (a partial has none,
+            # and would trace as ``jit__unknown``)
+            step.__name__ = "_fused_decode"
+            fn = jax.jit(step, donate_argnums=(8,))       # fast_pool
             self._fused_fns[k] = fn
         return fn
 
@@ -624,25 +644,29 @@ class PagedServingEngine:
             logits, counts, fpool, ppool = self._decode_core_pinned(
                 params, tokens, positions, block_tables, pool_sel,
                 positions + 1, fpool, ppool, remap, live)
-            sampled = jnp.argmax(logits[:, :cfg.vocab],
-                                 axis=-1).astype(jnp.int32)
+            with jax.named_scope("head"):
+                sampled = jnp.argmax(logits[:, :cfg.vocab],
+                                     axis=-1).astype(jnp.int32)
             nxt_tok, nxt_pos = self._advance_prompt(
                 positions, prompt_buf, prompt_len, sampled, b_idx)
-            tailcol = positions // page
-            sm = sysmon_mod.record(
-                sm, page_tables.reshape(-1), is_write=False,
-                valid=((col <= tailcol[:, None]) & live[:, None]).reshape(-1))
-            tails = page_tables[b_idx, tailcol]
-            sm = sysmon_mod.record(sm, tails, is_write=True, valid=live)
-            page_writes = page_writes.at[tails].add(live.astype(jnp.int32))
-            # pinned-tier wear: tails living in the pinned pool charge
-            # their physical row — under the carried remap — on device
-            # (amount 0 for fast tails)
-            tail_slot = block_tables[b_idx, tailcol]
-            tail_pin = pool_sel[b_idx, tailcol] * live.astype(jnp.int32)
-            tail_phys = remap[jnp.clip(tail_slot, 0, n_pin - 1)]
-            wear = wear_update(wear, tail_phys, amount=tail_pin)
-            pin_w = pin_w + tail_pin.sum()
+            with jax.named_scope("sysmon"):
+                tailcol = positions // page
+                sm = sysmon_mod.record(
+                    sm, page_tables.reshape(-1), is_write=False,
+                    valid=((col <= tailcol[:, None])
+                           & live[:, None]).reshape(-1))
+                tails = page_tables[b_idx, tailcol]
+                sm = sysmon_mod.record(sm, tails, is_write=True, valid=live)
+                page_writes = page_writes.at[tails].add(
+                    live.astype(jnp.int32))
+                # pinned-tier wear: tails living in the pinned pool charge
+                # their physical row — under the carried remap — on device
+                # (amount 0 for fast tails)
+                tail_slot = block_tables[b_idx, tailcol]
+                tail_pin = pool_sel[b_idx, tailcol] * live.astype(jnp.int32)
+                tail_phys = remap[jnp.clip(tail_slot, 0, n_pin - 1)]
+                wear = wear_update(wear, tail_phys, amount=tail_pin)
+                pin_w = pin_w + tail_pin.sum()
             if cfg.is_moe:
                 counts_acc = counts_acc + counts
             return (nxt_tok, nxt_pos, sm, fpool, ppool, wear, pin_w,
@@ -667,9 +691,10 @@ class PagedServingEngine:
         fn = self._fused_pinned_fns.get(k)
         if fn is None:
             # the pinned pool is not donated (see core.tiers)
-            fn = jax.jit(partial(self._fused_decode_pinned, k_steps=k,
-                                 gap_interval=self._gap_interval),
-                         donate_argnums=(9,))      # fast_pool
+            step = partial(self._fused_decode_pinned, k_steps=k,
+                           gap_interval=self._gap_interval)
+            step.__name__ = "_fused_decode_pinned"     # as in _get_fused
+            fn = jax.jit(step, donate_argnums=(9,))      # fast_pool
             self._fused_pinned_fns[k] = fn
         return fn
 
@@ -737,10 +762,12 @@ class PagedServingEngine:
             self.prefill_runner.warmup()
 
     # -- main loop (dispatch-boundary slow path) -----------------------------------
-    def _publish_dispatch_metrics(self, dt: float, k: int, batch: int) -> None:
+    def _publish_dispatch_metrics(self, dt: float, k: int, batch: int,
+                                  rows: int) -> None:
         """Per-dispatch latency + throughput metrics (looked up by name
         each dispatch so registry resets between sweep configs take
-        effect)."""
+        effect).  ``batch`` rows are live of the ``rows`` the dispatch
+        ran."""
         reg = obs.get_registry()
         reg.histogram("serving.dispatch_latency_s",
                       "wall time of one fused decode dispatch").observe(dt)
@@ -752,6 +779,9 @@ class PagedServingEngine:
         reg.counter("serving.dispatches", "decode dispatches issued").inc()
         reg.counter("serving.tokens_sampled",
                     "tokens sampled across all rows").inc(k * batch)
+        reg.counter("serving.decode_row_slots",
+                    "row-steps the decode dispatches ran, padding rows "
+                    "included").inc(k * rows)
         for qn, qv in self.batcher.depths().items():
             reg.gauge(f"serving.queue_{qn}",
                       f"scheduler {qn} queue depth").set(qv)
@@ -835,26 +865,30 @@ class PagedServingEngine:
         pt = self.pinned_tier
         Pp = pr.table_pages
         pages_rows = [r.pages for r in segs]
-        if pt is None:
-            page_tables, block_tables = self.kv.fill_tables(pages_rows, Pp)
-            pool_sel = None
-            wear_tr = None
-        else:
-            page_tables, block_tables, pool_sel = self.kv.fill_tables_mixed(
-                pages_rows, Pp)
-            wear_tr = store.wear_by_tier.get(pt)
-            if not pool_sel.any():
-                # all prompt pages landed tier-0 resident: single-pool
-                # dispatch (same downgrade the decode boundary applies)
-                pt = None
+        with obs.span("serve.tables", step=self.step_count):
+            if pt is None:
+                page_tables, block_tables = self.kv.fill_tables(pages_rows,
+                                                                Pp)
                 pool_sel = None
                 wear_tr = None
-        a = pr.build_args(group, block_tables, pool_sel)
+            else:
+                page_tables, block_tables, pool_sel = \
+                    self.kv.fill_tables_mixed(pages_rows, Pp)
+                wear_tr = store.wear_by_tier.get(pt)
+                if not pool_sel.any():
+                    # all prompt pages landed tier-0 resident: single-pool
+                    # dispatch (same downgrade the decode boundary applies)
+                    pt = None
+                    pool_sel = None
+                    wear_tr = None
+            a = pr.build_args(group, block_tables, pool_sel)
         n_tok = group.total_tokens
         t0 = time.perf_counter()
         with obs.span("serve.prefill", step=self.step_count,
                       bucket=group.bucket, segments=len(segs),
-                      tokens=n_tok):
+                      tokens=n_tok) as prefill_sp:
+            if obs.tracing_enabled():
+                prefill_sp.set(rids=[r.rid for r in segs])
             if pt is None:
                 fn = pr.get_plain(group.bucket)
                 first_d, seg_logits, ecounts, store.fast_pool = fn(
@@ -880,50 +914,56 @@ class PagedServingEngine:
                     jnp.asarray(a["write_off"]),
                     jnp.asarray(a["seg_last"]), store.fast_pool, ppool.data,
                     remap_arr)
-            first = np.asarray(first_d)
+            with obs.span("serve.prefill.wait"):
+                first = np.asarray(first_d)
         dt = time.perf_counter() - t0
         self.last_logits = seg_logits
         self.last_rows = [r.rid for r in segs]
-        reg = obs.get_registry()
-        reg.histogram("serving.prefill_latency_s",
-                      "wall time of one packed prefill dispatch").observe(dt)
-        reg.counter("serving.prefill_dispatches",
-                    "packed prefill dispatches issued").inc()
-        reg.counter("serving.prefill_tokens",
-                    "prompt tokens ingested via prefill").inc(n_tok)
+        with obs.span("serve.account", step=self.step_count):
+            reg = obs.get_registry()
+            reg.histogram("serving.prefill_latency_s",
+                          "wall time of one packed prefill dispatch"
+                          ).observe(dt)
+            reg.counter("serving.prefill_dispatches",
+                        "packed prefill dispatches issued").inc()
+            reg.counter("serving.prefill_tokens",
+                        "prompt tokens ingested via prefill").inc(n_tok)
+            reg.counter("serving.prefill_slots",
+                        "bucket positions the prefill dispatches ran, "
+                        "padding included").inc(group.bucket)
 
-        if self.expert_counts is not None:
-            self.expert_counts += np.asarray(ecounts, np.int64)
+            if self.expert_counts is not None:
+                self.expert_counts += np.asarray(ecounts, np.int64)
 
-        # boundary accounting: closed-form dense totals, bit-identical to
-        # the replay stream (reads: page j of an Lp-token segment is
-        # covered by Lp - j*page inner-step prefixes; writes: the tail
-        # lands on it min(page, Lp - j*page) times)
-        prompt_lens = [len(r.prompt) for r in segs]
-        d_reads, d_writes = replay_page_counts(
-            prompt_lens, page_tables, page, self.kv.n_pages)
-        self.sysmon = sysmon_mod.record_dense(
-            self.sysmon, jnp.asarray(d_reads, dtype=jnp.int32),
-            jnp.asarray(d_writes, dtype=jnp.int32))
-        if pt is None:
-            store.charge_fast_accesses(d_writes, int(d_reads.sum()))
-        else:
-            store.charge_accesses(d_writes, d_reads)
-            # pinned-pool writes charge wear per token write (the decode
-            # scan's wear_update totals, host-side) and refresh the
-            # written rows' checksums — the in-dispatch scatters bypass
-            # the host write paths that normally record both
-            wr_slots: list[int] = []
-            for si, lp in enumerate(prompt_lens):
-                for j in range((lp - 1) // page + 1):
-                    if pool_sel[si, j]:
-                        wr_slots.extend([int(block_tables[si, j])]
-                                        * min(page, lp - j * page))
-            if wear_tr is not None and wr_slots:
-                store._account_host_writes(
-                    pt, wear_tr.phys(np.asarray(wr_slots, np.int64)))
-            if store.integrity.enabled and wr_slots:
-                store.integrity.record(store, pt, sorted(set(wr_slots)))
+            # boundary accounting: closed-form dense totals, bit-identical
+            # to the replay stream (reads: page j of an Lp-token segment
+            # is covered by Lp - j*page inner-step prefixes; writes: the
+            # tail lands on it min(page, Lp - j*page) times)
+            prompt_lens = [len(r.prompt) for r in segs]
+            d_reads, d_writes = replay_page_counts(
+                prompt_lens, page_tables, page, self.kv.n_pages)
+            self.sysmon = sysmon_mod.record_dense(
+                self.sysmon, jnp.asarray(d_reads, dtype=jnp.int32),
+                jnp.asarray(d_writes, dtype=jnp.int32))
+            if pt is None:
+                store.charge_fast_accesses(d_writes, int(d_reads.sum()))
+            else:
+                store.charge_accesses(d_writes, d_reads)
+                # pinned-pool writes charge wear per token write (the
+                # decode scan's wear_update totals, host-side) and refresh
+                # the written rows' checksums — the in-dispatch scatters
+                # bypass the host write paths that normally record both
+                wr_slots: list[int] = []
+                for si, lp in enumerate(prompt_lens):
+                    for j in range((lp - 1) // page + 1):
+                        if pool_sel[si, j]:
+                            wr_slots.extend([int(block_tables[si, j])]
+                                            * min(page, lp - j * page))
+                if wear_tr is not None and wr_slots:
+                    store._account_host_writes(
+                        pt, wear_tr.phys(np.asarray(wr_slots, np.int64)))
+                if store.integrity.enabled and wr_slots:
+                    store.integrity.record(store, pt, sorted(set(wr_slots)))
 
         # lifecycle: the prompt is consumed and the first token sampled —
         # the request joins the decode batch at pos == len(prompt), or
@@ -957,7 +997,8 @@ class PagedServingEngine:
         gov = self.memos.governor
         limit = (gov.batch_limit(self.scfg.max_batch)
                  if gov is not None else None)
-        with obs.span("serve.admit", step=self.step_count):
+        admitted_rids = [] if obs.tracing_enabled() else None
+        with obs.span("serve.admit", step=self.step_count) as admit_sp:
             while True:
                 admitted = self.batcher.admit(limit)
                 if not admitted:
@@ -965,6 +1006,8 @@ class PagedServingEngine:
                 obs.get_registry().counter(
                     "serving.admissions",
                     "requests admitted into decode slots").inc(len(admitted))
+                if admitted_rids is not None:
+                    admitted_rids.extend(r.rid for r in admitted)
                 ok = True
                 stuck = False
                 need_room = 0
@@ -986,6 +1029,8 @@ class PagedServingEngine:
                         need_room - 1 if self.batcher.priority_aware
                         else None):
                     break
+            if admitted_rids:
+                admit_sp.set(rids=admitted_rids)
 
         # 1b) prefill: every newly admitted request (pos == 0 — nothing
         # processed yet) ingests its whole prompt in one packed bucketed
@@ -1061,45 +1106,46 @@ class PagedServingEngine:
         page = self.scfg.page_size
         store = self.kv.store
         pt = self.pinned_tier
-        positions = np.array([r.pos for r in active], np.int32)
-        prompt_lens = np.array([len(r.prompt) for r in active], np.int32)
-        tokens = np.array([(r.prompt + r.generated)[r.pos] for r in active],
-                          np.int32)
-        if pt is None:
-            page_tables, block_tables = self.kv.fill_tables(
-                [r.pages for r in active], P)
-            pool_sel = None
-            wear_tr = None
-        else:
-            page_tables, block_tables, pool_sel = self.kv.fill_tables_mixed(
-                [r.pages for r in active], P)
-            wear_tr = store.wear_by_tier.get(pt)
-            if not pool_sel.any():
-                # every page of this dispatch is tier-0 resident: the
-                # block tables are plain fast-pool slots, so take the
-                # single-pool fast path — the dual-pool dispatch (second
-                # gather + select per layer) only pays for itself when a
-                # page actually lives in the pinned tier
-                pt = None
+        with obs.span("serve.tables", step=self.step_count):
+            positions = np.array([r.pos for r in active], np.int32)
+            prompt_lens = np.array([len(r.prompt) for r in active], np.int32)
+            tokens = np.array([(r.prompt + r.generated)[r.pos]
+                               for r in active], np.int32)
+            if pt is None:
+                page_tables, block_tables = self.kv.fill_tables(
+                    [r.pages for r in active], P)
                 pool_sel = None
                 wear_tr = None
+            else:
+                page_tables, block_tables, pool_sel = \
+                    self.kv.fill_tables_mixed([r.pages for r in active], P)
+                wear_tr = store.wear_by_tier.get(pt)
+                if not pool_sel.any():
+                    # every page of this dispatch is tier-0 resident: the
+                    # block tables are plain fast-pool slots, so take the
+                    # single-pool fast path — the dual-pool dispatch
+                    # (second gather + select per layer) only pays for
+                    # itself when a page actually lives in the pinned tier
+                    pt = None
+                    pool_sel = None
+                    wear_tr = None
 
-        # the dispatch always runs max_batch rows: padding rows (live
-        # False) keep one program per K however far the batch drains, so
-        # warmup covers every shape serving emits
-        Bp = max(self.scfg.max_batch, B)
+            # the dispatch always runs max_batch rows: padding rows (live
+            # False) keep one program per K however far the batch drains,
+            # so warmup covers every shape serving emits
+            Bp = max(self.scfg.max_batch, B)
 
-        def rows(a):
-            pad = [(0, Bp - B)] + [(0, 0)] * (a.ndim - 1)
-            return jnp.asarray(np.pad(a, pad))
+            def rows(a):
+                pad = [(0, Bp - B)] + [(0, 0)] * (a.ndim - 1)
+                return jnp.asarray(np.pad(a, pad))
 
-        live = jnp.asarray(np.arange(Bp) < B)
-        d_tokens, d_pos = rows(tokens), rows(positions)
-        d_pages, d_blocks = rows(page_tables), rows(block_tables)
-        d_sel = rows(pool_sel) if pool_sel is not None else None
-        prompt_buf = np.zeros((Bp, P * page), np.int32)
-        for i, r in enumerate(active):
-            prompt_buf[i, :len(r.prompt)] = r.prompt
+            live = jnp.asarray(np.arange(Bp) < B)
+            d_tokens, d_pos = rows(tokens), rows(positions)
+            d_pages, d_blocks = rows(page_tables), rows(block_tables)
+            d_sel = rows(pool_sel) if pool_sel is not None else None
+            prompt_buf = np.zeros((Bp, P * page), np.int32)
+            for i, r in enumerate(active):
+                prompt_buf[i, :len(r.prompt)] = r.prompt
 
         dispatch_path = (("reference" if self.scfg.reference else "fused")
                          + ("+pinned" if pt is not None else ""))
@@ -1115,9 +1161,9 @@ class PagedServingEngine:
                     d_pos + 1, store.fast_pool, live)
                 logits = logits[:B]
                 # host-side argmax sampling: one transfer per token
-                sampled = np.asarray(
-                    jnp.argmax(logits[:, :self.cfg.vocab], axis=-1),
-                    np.int32)[None, :]
+                sampled_d = jnp.argmax(logits[:, :self.cfg.vocab], axis=-1)
+                with obs.span("serve.dispatch.wait"):
+                    sampled = np.asarray(sampled_d, np.int32)[None, :]
                 # standalone per-step SysMon records — the host round-trips
                 # the fused path folds into its scan
                 read_valid = (np.arange(P)[None, :]
@@ -1143,9 +1189,9 @@ class PagedServingEngine:
                         d_sel, d_pos + 1, store.fast_pool, ppool.data,
                         remap_arr, live)
                 logits = logits[:B]
-                sampled = np.asarray(
-                    jnp.argmax(logits[:, :self.cfg.vocab], axis=-1),
-                    np.int32)[None, :]
+                sampled_d = jnp.argmax(logits[:, :self.cfg.vocab], axis=-1)
+                with obs.span("serve.dispatch.wait"):
+                    sampled = np.asarray(sampled_d, np.int32)[None, :]
                 read_valid = (np.arange(P)[None, :]
                               <= (positions // page)[:, None])
                 self.sysmon = sysmon_mod.record(
@@ -1177,8 +1223,9 @@ class PagedServingEngine:
                     rows(prompt_lens), d_pages, d_blocks, self.sysmon,
                     store.fast_pool, live)
                 # one transfer per K tokens
-                sampled = np.asarray(sampled_d)[:, :B]
-                page_writes = np.asarray(page_writes_d)
+                with obs.span("serve.dispatch.wait"):
+                    sampled = np.asarray(sampled_d)[:, :B]
+                    page_writes = np.asarray(page_writes_d)
                 self.last_logits = logits[:B]
             else:
                 # -- fused K-step dual-pool dispatch: slow-tier KV appends
@@ -1201,8 +1248,9 @@ class PagedServingEngine:
                     rows(prompt_lens), d_pages, d_blocks, d_sel, self.sysmon,
                     store.fast_pool, ppool.data, wear_arr, remap_arr, gap0,
                     pending0, live)
-                sampled = np.asarray(sampled_d)[:, :B]
-                page_writes = np.asarray(page_writes_d)
+                with obs.span("serve.dispatch.wait"):
+                    sampled = np.asarray(sampled_d)[:, :B]
+                    page_writes = np.asarray(page_writes_d)
                 if wear_tr is not None:
                     n_pin_w = int(page_writes[store.tier == pt].sum())
                     n_adv = int(n_adv_out)
@@ -1221,32 +1269,34 @@ class PagedServingEngine:
                 self.last_logits = logits[:B]
         dispatch_dt = time.perf_counter() - t_disp0
         self.last_rows = [r.rid for r in active]
-        self._publish_dispatch_metrics(dispatch_dt, k, B)
+        with obs.span("serve.account", step=self.step_count):
+            self._publish_dispatch_metrics(dispatch_dt, k, B, Bp)
 
-        if self.expert_counts is not None:
-            self.expert_counts += np.asarray(ecounts, np.int64)
+            if self.expert_counts is not None:
+                self.expert_counts += np.asarray(ecounts, np.int64)
 
-        # 4) access accounting, vectorized: device-counted page writes
-        # bump versions in one add; reads are closed-form.  The dual-pool
-        # path splits the charge by each page's tier (the dispatch touched
-        # both the fast pool and the pinned tier).
-        if pt is None:
-            n_reads = int(((positions[:, None] + np.arange(k)[None, :])
-                           // page + 1).sum())
-            store.charge_fast_accesses(page_writes, n_reads)
-        else:
-            page_reads = self._page_read_counts(positions, page_tables, k)
-            store.charge_accesses(page_writes, page_reads)
-        # refresh checksums for pinned-pool rows the dispatch appended to
-        # (the in-scan tail writes bypass the host write paths that
-        # normally record them)
-        if pt is not None and store.integrity.enabled:
-            written = np.nonzero(page_writes > 0)[0]
-            wmask = (store.tier[written] == pt) & \
-                (store.slot[written] != NO_SLOT)
-            if wmask.any():
-                store.integrity.record(
-                    store, pt, np.unique(store.slot[written[wmask]]))
+            # 4) access accounting, vectorized: device-counted page writes
+            # bump versions in one add; reads are closed-form.  The
+            # dual-pool path splits the charge by each page's tier (the
+            # dispatch touched both the fast pool and the pinned tier).
+            if pt is None:
+                n_reads = int(((positions[:, None] + np.arange(k)[None, :])
+                               // page + 1).sum())
+                store.charge_fast_accesses(page_writes, n_reads)
+            else:
+                page_reads = self._page_read_counts(positions, page_tables,
+                                                    k)
+                store.charge_accesses(page_writes, page_reads)
+            # refresh checksums for pinned-pool rows the dispatch appended
+            # to (the in-scan tail writes bypass the host write paths that
+            # normally record them)
+            if pt is not None and store.integrity.enabled:
+                written = np.nonzero(page_writes > 0)[0]
+                wmask = (store.tier[written] == pt) & \
+                    (store.slot[written] != NO_SLOT)
+                if wmask.any():
+                    store.integrity.record(
+                        store, pt, np.unique(store.slot[written[wmask]]))
 
         # 5) advance sequences from the returned token block: tokens
         # sampled at inner step s >= emit_from[i] are new generations
@@ -1320,19 +1370,21 @@ class PagedServingEngine:
                 if not self.scfg.overlap_plan:
                     self._promote_all(list(self.batcher.active))
 
-        if not self.scfg.memos_enabled:
-            # no memos pass ever rolls the bandwidth-headroom window, so
-            # roll it at dispatch boundaries — otherwise cascade targeting
-            # would rank tiers by lifetime-cumulative inflow
-            store.roll_traffic_window()
+        with obs.span("serve.account", step=self.step_count):
+            if not self.scfg.memos_enabled:
+                # no memos pass ever rolls the bandwidth-headroom window,
+                # so roll it at dispatch boundaries — otherwise cascade
+                # targeting would rank tiers by lifetime-cumulative inflow
+                store.roll_traffic_window()
 
-        # 7) fault-injection tick, strictly *after* every write path of
-        # this boundary has recorded its checksums and *before* the next
-        # boundary's pre-dispatch verify — injected corruption always has
-        # a detection point ahead of the next serve
-        inj = get_injector()
-        if inj.enabled:
-            inj.tick(store)
+            # 7) fault-injection tick, strictly *after* every write path
+            # of this boundary has recorded its checksums and *before*
+            # the next boundary's pre-dispatch verify — injected
+            # corruption always has a detection point ahead of the next
+            # serve
+            inj = get_injector()
+            if inj.enabled:
+                inj.tick(store)
 
         self.step_count += k
         stats["decode_block"] = k

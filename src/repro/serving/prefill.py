@@ -214,30 +214,35 @@ class PrefillRunner:
                       if cfg.is_moe else jnp.int32(0))
         for l in range(cfg.n_layers):
             lp = T._tree_slice(params["layers"], l)
-            x = L.rms_norm(h, lp["ln1"], eps=cfg.norm_eps,
-                           gemma_style=cfg.gemma_norm)
-            p = T._attn_from_dict(lp["attn"])
-            q, k, v = attn_mod.project_qkv(p, x, cos, sin)
-            dtype = fast_pool.dtype
-            fast_pool = fast_pool.at[write_slot, l, 0, write_off].set(
-                k[0].astype(dtype), mode="drop")
-            fast_pool = fast_pool.at[write_slot, l, 1, write_off].set(
-                v[0].astype(dtype), mode="drop")
-            out = paged_attention_prefill(q[0], fast_pool[:, l, 0],
-                                          fast_pool[:, l, 1], row_tables,
-                                          lengths)
-            out = jnp.einsum("bhk,hkd->bd", out.reshape(
-                Lb, cfg.n_heads, cfg.head_dim), p.wo)[None, :, :]
-            h = h + out
-            h, counts, _ = T._ffn_block(lp, cfg, h, None, valid=valid)
+            with jax.named_scope("attention"):
+                x = L.rms_norm(h, lp["ln1"], eps=cfg.norm_eps,
+                               gemma_style=cfg.gemma_norm)
+                p = T._attn_from_dict(lp["attn"])
+                q, k, v = attn_mod.project_qkv(p, x, cos, sin)
+            with jax.named_scope("kv_append"):
+                dtype = fast_pool.dtype
+                fast_pool = fast_pool.at[write_slot, l, 0, write_off].set(
+                    k[0].astype(dtype), mode="drop")
+                fast_pool = fast_pool.at[write_slot, l, 1, write_off].set(
+                    v[0].astype(dtype), mode="drop")
+            with jax.named_scope("attention"):
+                out = paged_attention_prefill(q[0], fast_pool[:, l, 0],
+                                              fast_pool[:, l, 1], row_tables,
+                                              lengths)
+                out = jnp.einsum("bhk,hkd->bd", out.reshape(
+                    Lb, cfg.n_heads, cfg.head_dim), p.wo)[None, :, :]
+                h = h + out
+            with jax.named_scope("mlp"):
+                h, counts, _ = T._ffn_block(lp, cfg, h, None, valid=valid)
             if cfg.is_moe and counts is not None:
                 counts_acc = counts_acc + counts
-        h = L.rms_norm(h, params["final_norm"], eps=cfg.norm_eps,
-                       gemma_style=cfg.gemma_norm)
-        logits = T.logits_out(params, cfg, h)[0]          # [L, Vp]
-        seg_logits = logits[seg_last]                     # [S, Vp]
-        first = jnp.argmax(seg_logits[:, :cfg.vocab],
-                           axis=-1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            h = L.rms_norm(h, params["final_norm"], eps=cfg.norm_eps,
+                           gemma_style=cfg.gemma_norm)
+            logits = T.logits_out(params, cfg, h)[0]          # [L, Vp]
+            seg_logits = logits[seg_last]                     # [S, Vp]
+            first = jnp.argmax(seg_logits[:, :cfg.vocab],
+                               axis=-1).astype(jnp.int32)
         return first, seg_logits, counts_acc, fast_pool
 
     def _core_pinned(self, params, tokens, local_pos, row_tables, pool_sel,
@@ -274,39 +279,44 @@ class PrefillRunner:
                       if cfg.is_moe else jnp.int32(0))
         for l in range(cfg.n_layers):
             lp = T._tree_slice(params["layers"], l)
-            x = L.rms_norm(h, lp["ln1"], eps=cfg.norm_eps,
-                           gemma_style=cfg.gemma_norm)
-            p = T._attn_from_dict(lp["attn"])
-            q, k, v = attn_mod.project_qkv(p, x, cos, sin)
-            fd, pd = fast_pool.dtype, pinned_pool.dtype
-            fast_pool = fast_pool.at[f_idx, l, 0, write_off].set(
-                k[0].astype(fd), mode="drop")
-            fast_pool = fast_pool.at[f_idx, l, 1, write_off].set(
-                v[0].astype(fd), mode="drop")
-            pinned_pool = pinned_pool.at[p_idx, l, 0, write_off].set(
-                k[0].astype(pd), mode="drop")
-            pinned_pool = pinned_pool.at[p_idx, l, 1, write_off].set(
-                v[0].astype(pd), mode="drop")
-            k_pages = jnp.where(sel_pages,
-                                pinned_pool[row_tables, l, 0].astype(fd),
-                                fast_pool[row_tables, l, 0])
-            v_pages = jnp.where(sel_pages,
-                                pinned_pool[row_tables, l, 1].astype(fd),
-                                fast_pool[row_tables, l, 1])
-            out = paged_attention_prefill_pages(q[0], k_pages, v_pages,
-                                                lengths)
-            out = jnp.einsum("bhk,hkd->bd", out.reshape(
-                Lb, cfg.n_heads, cfg.head_dim), p.wo)[None, :, :]
-            h = h + out
-            h, counts, _ = T._ffn_block(lp, cfg, h, None, valid=valid)
+            with jax.named_scope("attention"):
+                x = L.rms_norm(h, lp["ln1"], eps=cfg.norm_eps,
+                               gemma_style=cfg.gemma_norm)
+                p = T._attn_from_dict(lp["attn"])
+                q, k, v = attn_mod.project_qkv(p, x, cos, sin)
+            with jax.named_scope("kv_append"):
+                fd, pd = fast_pool.dtype, pinned_pool.dtype
+                fast_pool = fast_pool.at[f_idx, l, 0, write_off].set(
+                    k[0].astype(fd), mode="drop")
+                fast_pool = fast_pool.at[f_idx, l, 1, write_off].set(
+                    v[0].astype(fd), mode="drop")
+                pinned_pool = pinned_pool.at[p_idx, l, 0, write_off].set(
+                    k[0].astype(pd), mode="drop")
+                pinned_pool = pinned_pool.at[p_idx, l, 1, write_off].set(
+                    v[0].astype(pd), mode="drop")
+            with jax.named_scope("attention"):
+                k_pages = jnp.where(sel_pages,
+                                    pinned_pool[row_tables, l, 0].astype(fd),
+                                    fast_pool[row_tables, l, 0])
+                v_pages = jnp.where(sel_pages,
+                                    pinned_pool[row_tables, l, 1].astype(fd),
+                                    fast_pool[row_tables, l, 1])
+                out = paged_attention_prefill_pages(q[0], k_pages, v_pages,
+                                                    lengths)
+                out = jnp.einsum("bhk,hkd->bd", out.reshape(
+                    Lb, cfg.n_heads, cfg.head_dim), p.wo)[None, :, :]
+                h = h + out
+            with jax.named_scope("mlp"):
+                h, counts, _ = T._ffn_block(lp, cfg, h, None, valid=valid)
             if cfg.is_moe and counts is not None:
                 counts_acc = counts_acc + counts
-        h = L.rms_norm(h, params["final_norm"], eps=cfg.norm_eps,
-                       gemma_style=cfg.gemma_norm)
-        logits = T.logits_out(params, cfg, h)[0]
-        seg_logits = logits[seg_last]
-        first = jnp.argmax(seg_logits[:, :cfg.vocab],
-                           axis=-1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            h = L.rms_norm(h, params["final_norm"], eps=cfg.norm_eps,
+                           gemma_style=cfg.gemma_norm)
+            logits = T.logits_out(params, cfg, h)[0]
+            seg_logits = logits[seg_last]
+            first = jnp.argmax(seg_logits[:, :cfg.vocab],
+                               axis=-1).astype(jnp.int32)
         return (first, seg_logits, counts_acc, fast_pool,
                 to_pinned_host(pinned_pool))
 

@@ -10,14 +10,22 @@ relies on:
   * ring-buffer wraparound — a full ring drops oldest events, never
     stalls or grows;
   * disabled-mode zero cost — disabled tracing records zero events,
-    retains zero attributes, and hands out one shared no-op span;
+    retains zero attributes, enters no profiler annotation, and hands
+    out one shared no-op span;
+  * one clock — an enabled span is also a ``jax.profiler``
+    annotation, so a recorded profiler trace holds it as a host event
+    with its attributes as stats;
+  * the serving step's spans (tables, dispatch and prefill waits,
+    accounting, request ids) and its padded-work counters;
   * log-bucketed histogram quantiles, the exporters' formats, and the
     MemosReport to_dict/from_dict/flat_metrics serialization contract.
 """
+import glob
 import json
 import threading
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -28,6 +36,7 @@ from repro.core.memos import (MemosConfig, MemosManager, MemosReport,
                               aggregate_reports)
 from repro.core.migration import MigrationStats
 from repro.core.tiers import TierConfig, TierStore
+from repro.obs import trace as obs_trace
 from repro.obs.trace import NULL_SPAN, Tracer
 
 
@@ -67,7 +76,8 @@ def test_span_nesting_and_attrs():
 def test_ring_wraparound_keeps_newest():
     tr = Tracer(capacity=8, enabled=True)
     for i in range(20):
-        tr.instant(f"e{i}")
+        with tr.span(f"e{i}"):
+            pass
     ev = tr.events()
     assert [e.name for e in ev] == [f"e{i}" for i in range(12, 20)]
     assert tr.n_recorded == 20
@@ -83,12 +93,11 @@ def test_disabled_mode_records_nothing():
     assert tr.span("y") is NULL_SPAN
     with s:
         s.set(more="attrs")
-    tr.instant("z")
     assert tr.events() == [] and tr.n_recorded == 0
     # the module-level API takes the same fast path
     assert not obs.tracing_enabled()
-    assert obs.span("serve.dispatch", k=16) is NULL_SPAN
-    obs.instant("nope")
+    with obs.span("serve.dispatch", k=16) as sp:
+        assert sp is NULL_SPAN
     assert obs.get_tracer().n_recorded == 0
 
 
@@ -101,6 +110,95 @@ def test_configure_flip_and_capacity():
     assert obs.get_tracer().capacity == 16
     assert obs.get_tracer().n_recorded == 0
     assert obs.tracing_enabled()                # flag survives the resize
+
+
+# =============================================================================
+# one clock: enabled spans are profiler annotations
+# =============================================================================
+
+def _profiler_host_events(log_dir) -> dict:
+    """Host events of a recorded profiler trace by name: (start, end,
+    stats) of the last event of each name."""
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(f"{log_dir}/plugins/profile/*/*.xplane.pb"))[-1]
+    return {e.name: (e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for p in ProfileData.from_file(path).planes
+            if p.name.startswith("/host:")
+            for ln in p.lines for e in ln.events}
+
+
+def test_enabled_span_is_a_profiler_host_event(tmp_path):
+    obs.configure(trace=True)
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("serve.prefill", step=7, rids=[3, 4], tokens=5) as sp:
+            sp.set(bucket=256, path="fused")
+            with obs.span("serve.prefill.wait"):
+                time.sleep(0.002)
+    host = _profiler_host_events(tmp_path)
+    a, b, stats = host["serve.prefill"]
+    # a list travels joined by ";" (the profiler splits metadata at ",")
+    assert stats == {"step": 7, "rids": "3;4", "tokens": 5, "bucket": 256,
+                     "path": "fused"}
+    wa, wb, _ = host["serve.prefill.wait"]
+    assert a <= wa < wb <= b and wb - wa >= 2e6
+    # the ring keeps the attributes as they were given
+    (ev,) = [e for e in obs.get_tracer().events()
+             if e.name == "serve.prefill"]
+    assert ev.attrs["rids"] == [3, 4] and ev.attrs["bucket"] == 256
+
+
+def test_disabled_span_enters_no_annotation(monkeypatch):
+    entered = []
+
+    class CountingAnnotation:
+        def __init__(self, name, **meta):
+            self.name = name
+
+        @staticmethod
+        def is_enabled():                   # a profiler trace is running
+            return True
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            return False
+
+        def set_metadata(self, **meta):
+            pass
+
+    # the class the tracer enters (it imports jax.profiler's once)
+    monkeypatch.setattr(obs_trace, "_annotation_cls", CountingAnnotation)
+    with obs.span("serve.dispatch", k=8) as sp:
+        sp.set(batch=3)
+        assert sp is NULL_SPAN
+    assert entered == []
+    obs.configure(trace=True)               # the patch is what spans enter
+    with obs.span("serve.dispatch", k=8):
+        with obs.span("serve.dispatch.wait"):
+            pass
+    assert entered == ["serve.dispatch", "serve.dispatch.wait"]
+
+
+def test_span_outside_a_profiler_trace_enters_no_annotation(monkeypatch):
+    """Enabled, but with no profiler trace running: the span is recorded
+    and makes no annotation (the profiler would drop it anyway)."""
+    made = []
+
+    class NoProfile:
+        def __init__(self, name, **meta):
+            made.append(name)
+
+        is_enabled = staticmethod(jax.profiler.TraceAnnotation.is_enabled)
+
+    assert not NoProfile.is_enabled()
+    obs.configure(trace=True)
+    monkeypatch.setattr(obs_trace, "_annotation_cls", NoProfile)
+    with obs.span("serve.dispatch", k=8) as sp:
+        sp.set(batch=3)
+    assert made == []
+    (ev,) = obs.get_tracer().events()
+    assert ev.name == "serve.dispatch" and ev.attrs == {"k": 8, "batch": 3}
 
 
 # =============================================================================
@@ -149,19 +247,20 @@ def test_registry_kind_mismatch_and_flat():
 def test_chrome_trace_export(tmp_path):
     obs.configure(trace=True)
     with obs.span("outer", step=1):
-        obs.instant("marker")
+        with obs.span("inner", rids=[3, 4]):
+            pass
     p = obs.export.write_chrome_trace(tmp_path / "t.json", obs.get_tracer())
     doc = json.loads(p.read_text())
     evs = doc["traceEvents"]
     meta = [e for e in evs if e["ph"] == "M"]
-    spans = [e for e in evs if e["ph"] == "X"]
-    insts = [e for e in evs if e["ph"] == "i"]
+    spans = {e["name"]: e for e in evs if e["ph"] == "X"}
     assert meta and meta[0]["name"] == "thread_name"
-    assert len(spans) == 1 and spans[0]["name"] == "outer"
-    assert spans[0]["args"] == {"step": 1} and spans[0]["dur"] >= 0
-    assert insts[0]["s"] == "t"
+    assert len(spans) == 2 == len(evs) - len(meta)
+    outer, inner = spans["outer"], spans["inner"]
+    assert outer["args"] == {"step": 1} and outer["dur"] >= inner["dur"]
+    assert inner["args"] == {"rids": "[3, 4]"}
     # timestamps rebase to the earliest event
-    assert min(e["ts"] for e in spans + insts) == 0
+    assert outer["ts"] == 0 and inner["ts"] >= 0
     assert doc["otherData"]["dropped_events"] == 0
 
 
@@ -176,15 +275,6 @@ def test_prometheus_text():
     assert "repro_store_t0_used 7" in text
     assert 'repro_serving_dispatch_latency_s_bucket{le="+Inf"} 4' in text
     assert "repro_serving_dispatch_latency_s_count 4" in text
-
-
-def test_jsonl_export():
-    obs.configure(trace=True)
-    with obs.span("a"):
-        pass
-    lines = obs.export.to_jsonl(obs.get_tracer()).strip().splitlines()
-    rec = json.loads(lines[0])
-    assert rec["name"] == "a" and rec["ph"] == "X" and rec["thread"]
 
 
 # =============================================================================
@@ -331,3 +421,110 @@ def test_disabled_tracing_still_publishes_metrics():
     assert obs.get_tracer().n_recorded == 0
     flat = obs.get_registry().flat()
     assert flat["memos.passes"] == len(mgr.reports) > 0
+
+
+# =============================================================================
+# the serving step: spans where the host works, request ids, padded work
+# =============================================================================
+
+MAX_BATCH = 4
+PROMPTS = [list(range(1, 1 + n)) for n in (9, 20, 5, 30, 12, 17)]
+
+
+@pytest.fixture(scope="module")
+def served():
+    """Six prompts through a smoke-size engine with prefill on and
+    tracing enabled: more requests than decode rows, so admission and
+    prefill happen at several steps and decode runs padding rows."""
+    from repro.configs import registry, smoke
+    from repro.models import transformer as T
+    from repro.serving import PagedServingEngine, ServeConfig
+    cfg = smoke(registry()["qwen3_4b"])
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    eng = PagedServingEngine(cfg, params, ServeConfig(
+        page_size=8, max_batch=MAX_BATCH, fast_slots=64, slow_slots=128,
+        max_pages_per_seq=8, memos_enabled=False, decode_block=4,
+        prefill=True, prefill_max_bucket=64))
+    obs.configure(trace=True, capacity=65536)   # room for every span
+    obs.reset()
+    try:
+        reqs = [eng.submit(p, max_new=5 + i) for i, p in enumerate(PROMPTS)]
+        eng.run(max_steps=500)
+        events = obs.get_tracer().events()
+        counters = {n: m.value for n, m in obs.get_registry().collect().items()
+                    if isinstance(m, obs.Counter)}
+    finally:
+        obs.configure(trace=False)
+        obs.reset()
+        eng.close()
+    assert all(r.done and r.error is None for r in reqs)
+    return reqs, events, counters
+
+
+def _spans(events, name):
+    return [e for e in events if e.name == name]
+
+
+def _inside(child, parent) -> bool:
+    return (parent.ts_ns <= child.ts_ns and child.ts_ns + child.dur_ns
+            <= parent.ts_ns + parent.dur_ns)
+
+
+@pytest.mark.parametrize("outer,inner", [
+    ("serve.dispatch", "serve.dispatch.wait"),
+    ("serve.prefill", "serve.prefill.wait"),
+])
+def test_wait_spans_nest_in_their_dispatch(served, outer, inner):
+    _, events, _ = served
+    outers, inners = _spans(events, outer), _spans(events, inner)
+    assert outers and len(inners) == len(outers)
+    for w in inners:
+        assert sum(_inside(w, o) for o in outers) == 1
+
+
+def test_host_work_between_dispatches_is_spanned(served):
+    _, events, _ = served
+    tables, account = (_spans(events, "serve.tables"),
+                       _spans(events, "serve.account"))
+    dispatches = _spans(events, "serve.dispatch")
+    prefills = _spans(events, "serve.prefill")
+    # one table build before every dispatch of either kind; accounting
+    # after every prefill, and twice per decode step
+    assert len(tables) == len(dispatches) + len(prefills)
+    assert len(account) == 2 * len(dispatches) + len(prefills)
+    # the new spans never overlap the spans the benchmark already reads
+    old = [e for e in events if e.name in (
+        "serve.admit", "serve.provision", "serve.retire", "serve.dispatch",
+        "serve.prefill")]
+    for e in tables + account:
+        for o in old:
+            assert (e.ts_ns >= o.ts_ns + o.dur_ns
+                    or e.ts_ns + e.dur_ns <= o.ts_ns), (e.name, o.name)
+
+
+def test_request_ids_on_prefill_and_admit(served):
+    reqs, events, _ = served
+    prefilled = [rid for e in _spans(events, "serve.prefill")
+                 for rid in e.attrs["rids"]]
+    admitted = [rid for e in _spans(events, "serve.admit")
+                if e.attrs and "rids" in e.attrs for rid in e.attrs["rids"]]
+    ids = [r.rid for r in reqs]
+    assert sorted(prefilled) == ids == sorted(admitted)
+    for e in _spans(events, "serve.prefill"):
+        assert len(e.attrs["rids"]) == e.attrs["segments"]
+        assert e.attrs["tokens"] == sum(len(PROMPTS[ids.index(rid)])
+                                        for rid in e.attrs["rids"])
+
+
+def test_padded_work_counters(served):
+    _, events, counters = served
+    ks = [e.attrs["k"] for e in _spans(events, "serve.dispatch")]
+    buckets = [e.attrs["bucket"] for e in _spans(events, "serve.prefill")]
+    assert counters["serving.decode_row_slots"] == sum(ks) * MAX_BATCH
+    assert counters["serving.prefill_slots"] == sum(buckets)
+    # live work is a share of the padded work, and the prompts are
+    # what prefill ingested
+    assert 0 < counters["serving.tokens_sampled"] \
+        < counters["serving.decode_row_slots"]
+    assert counters["serving.prefill_tokens"] == sum(map(len, PROMPTS)) \
+        <= counters["serving.prefill_slots"]
