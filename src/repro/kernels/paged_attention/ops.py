@@ -2,15 +2,19 @@
 
 Same three execution paths as ``kernels/page_gather`` / ``wear_update``:
 
-  * TPU            — the scalar-prefetch Pallas kernel, compiled;
-  * explicit       — ``interpret=True`` runs the Pallas kernel in
+  * TPU            — the Pallas kernel, compiled: it reads the whole
+                     tier-0 pool in place and DMAs only each row's live
+                     pages (``paged_attention.py``);
+  * explicit       — ``interpret=`` (``True`` or a
+                     ``pltpu.InterpretParams``) runs the Pallas kernel in
                      interpreter mode (kernel-parity tests only);
   * other backends — the pure-jnp gather+softmax reference, jit-compiled
-                     by XLA.  Interpreter-mode Pallas unrolls the whole
-                     (B, Hkv, n_pages) grid into emulation HLO, which
-                     dominated the serving decode step on CPU hosts —
-                     the XLA path keeps the fused multi-token dispatch
-                     compute-bound instead of emulation-bound.
+                     by XLA: it gathers the rows' pages of the layer
+                     straight out of the pool.  Interpreter-mode Pallas
+                     emulates every DMA, which would dominate the serving
+                     decode step on CPU hosts — the XLA path keeps the
+                     fused multi-token dispatch compute-bound instead of
+                     emulation-bound.
 """
 from __future__ import annotations
 
@@ -24,30 +28,30 @@ from .ref import paged_attention_pages_ref, paged_attention_ref
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
+def paged_attention(q: jnp.ndarray, pool: jnp.ndarray, layer,
                     block_table: jnp.ndarray, lengths: jnp.ndarray, *,
-                    interpret: bool | None = None) -> jnp.ndarray:
-    """q: [B, Hq, D] decode queries; k/v_pool: [n_slots, page, Hkv, D];
-    block_table: [B, n_pages]; lengths: [B].  Returns [B, Hq, D]."""
+                    interpret=None) -> jnp.ndarray:
+    """q: [B, Hq, D] one query per row; pool: the whole tier-0 pool
+    [n_slots, n_layers, 2, page, Hkv, D], read in place; layer: int32
+    scalar, the layer attended; block_table: [B, n_pages]; lengths: [B]
+    (row b reads its first ceil(lengths[b] / page) pages; length 0 reads
+    nothing and outputs zeros).  Returns [B, Hq, D]."""
     B, Hq, D = q.shape
-    Hkv = k_pool.shape[2]
+    Hkv = pool.shape[4]
     G = Hq // Hkv
     scale = D ** -0.5
     qg = (q * scale).reshape(B, Hkv, G, D)
+    args = (qg, pool, jnp.asarray(layer, jnp.int32),
+            block_table.astype(jnp.int32), lengths.astype(jnp.int32))
     if interpret is None and jax.default_backend() != "tpu":
-        out = paged_attention_ref(qg, k_pool, v_pool,
-                                  block_table.astype(jnp.int32),
-                                  lengths.astype(jnp.int32))
+        out = paged_attention_ref(*args)
     else:
-        out = paged_attention_pooled(qg, k_pool, v_pool,
-                                     block_table.astype(jnp.int32),
-                                     lengths.astype(jnp.int32),
-                                     interpret=bool(interpret))
+        out = paged_attention_pooled(*args, interpret=interpret or False)
     return out.reshape(B, Hq, D)
 
 
-def paged_attention_prefill(q_all: jnp.ndarray, k_pool: jnp.ndarray,
-                            v_pool: jnp.ndarray, block_tables: jnp.ndarray,
+def paged_attention_prefill(q_all: jnp.ndarray, pool: jnp.ndarray, layer,
+                            block_tables: jnp.ndarray,
                             lengths: jnp.ndarray) -> jnp.ndarray:
     """Full-sequence prefill attention over freshly written KV pages.
 
@@ -59,10 +63,11 @@ def paged_attention_prefill(q_all: jnp.ndarray, k_pool: jnp.ndarray,
     path does, so position p of a packed segment produces bitwise the
     same output as a decode step at position p over the same pool —
     segments can never attend across packing boundaries because foreign
-    pages simply aren't in the row's table.  Seam for a future Pallas
-    flash-prefill variant; today it reuses ``paged_attention`` verbatim.
+    pages simply aren't in the row's table.  It is ``paged_attention``
+    itself: a row fetches only the pages of its causal prefix, and a
+    padding row (length 0) fetches none.
     """
-    return paged_attention(q_all, k_pool, v_pool, block_tables, lengths)
+    return paged_attention(q_all, pool, layer, block_tables, lengths)
 
 
 def paged_attention_prefill_pages(q_all: jnp.ndarray, k_pages: jnp.ndarray,

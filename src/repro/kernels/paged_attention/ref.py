@@ -26,10 +26,15 @@ def paged_attention_pages_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
     return out.astype(q.dtype)
 
 
-def paged_attention_ref(q: jnp.ndarray, k_pool: jnp.ndarray,
-                        v_pool: jnp.ndarray, block_table: jnp.ndarray,
+def paged_attention_ref(q: jnp.ndarray, pool: jnp.ndarray,
+                        layer: jnp.ndarray, block_table: jnp.ndarray,
                         lengths: jnp.ndarray) -> jnp.ndarray:
-    """Same signature as paged_attention_pooled (q pre-scaled)."""
-    # gather pages -> dense, then attend (shared with the dual-pool path)
-    return paged_attention_pages_ref(q, k_pool[block_table],
-                                     v_pool[block_table], lengths)
+    """Same signature as paged_attention_pooled (q pre-scaled): gathers
+    the rows' pages of ``layer`` straight out of the whole
+    [n_slots, n_layers, 2, page, Hkv, D] pool, then attends (shared with
+    the dual-pool path).  A row of length 0 attends to nothing and
+    outputs zeros, as the kernel's does."""
+    out = paged_attention_pages_ref(q, pool[block_table, layer, 0],
+                                    pool[block_table, layer, 1], lengths)
+    return jnp.where(lengths[:, None, None, None] > 0, out,
+                     jnp.zeros_like(out))

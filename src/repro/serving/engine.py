@@ -342,9 +342,11 @@ class PagedServingEngine:
         stack through paged_attention.  tokens [B] i32; positions [B];
         block_tables [B,P] fast-slot ids; lengths [B] (incl. current
         token); live [B] bool, False for the padding rows that fill the
-        dispatch to its fixed width (their K/V writes are dropped and
-        they count no experts).  Returns (logits [B,Vp],
-        expert_counts|0, new fast_pool)."""
+        dispatch to its fixed width (their K/V writes are dropped, they
+        attend to nothing and they count no experts).  The kernel reads
+        each layer's pages straight out of ``fast_pool``, after that
+        layer's append.  Returns (logits [B,Vp], expert_counts|0, new
+        fast_pool)."""
         cfg = self.cfg
         page = self.scfg.page_size
         B = tokens.shape[0]
@@ -355,6 +357,9 @@ class PagedServingEngine:
         slot = jnp.where(live, block_tables[b_idx, positions // page],
                          fast_pool.shape[0])
         off = positions % page
+        # padding rows attend to nothing: the kernel fetches no page for
+        # them
+        lengths = jnp.where(live, lengths, 0)
         counts_acc = (jnp.zeros((cfg.n_experts,), jnp.int32)
                       if cfg.is_moe else jnp.int32(0))
         for l in range(cfg.n_layers):
@@ -371,8 +376,7 @@ class PagedServingEngine:
                 fast_pool = fast_pool.at[slot, l, 1, off].set(
                     v[:, 0].astype(dtype), mode="drop")
             with jax.named_scope("attention"):
-                out = paged_attention(q[:, 0], fast_pool[:, l, 0],
-                                      fast_pool[:, l, 1], block_tables,
+                out = paged_attention(q[:, 0], fast_pool, l, block_tables,
                                       lengths)
                 out = jnp.einsum("bhk,hkd->bd", out.reshape(
                     B, cfg.n_heads, cfg.head_dim), p.wo)[:, None, :]
@@ -786,6 +790,20 @@ class PagedServingEngine:
             reg.gauge(f"serving.queue_{qn}",
                       f"scheduler {qn} queue depth").set(qv)
 
+    def _publish_page_fetches(self, pages: int, slots: int) -> None:
+        """Paged-attention work of one single-pool dispatch: per layer,
+        the kernel fetched ``pages`` live pages, of the ``slots`` (padded
+        row, table column) pairs a walk of every row's whole table would
+        visit; the counters add both for every layer."""
+        n = self.cfg.n_layers
+        reg = obs.get_registry()
+        reg.counter("serving.attn_pages_fetched",
+                    "KV pages the paged-attention kernel fetched, all "
+                    "layers").inc(pages * n)
+        reg.counter("serving.attn_page_slots",
+                    "padded rows x table columns of the paged-attention "
+                    "calls, all layers").inc(slots * n)
+
     def _publish_first_token(self, req: Request) -> None:
         """Wall-clock TTFT, aggregate + per-tenant (metric-name label)."""
         if req.ttft_s is None:
@@ -947,6 +965,10 @@ class PagedServingEngine:
                 jnp.asarray(d_writes, dtype=jnp.int32))
             if pt is None:
                 store.charge_fast_accesses(d_writes, int(d_reads.sum()))
+                lens = np.asarray(a["lengths"])
+                self._publish_page_fetches(
+                    int(((lens + page - 1) // page).sum()),
+                    lens.shape[0] * Pp)
             else:
                 store.charge_accesses(d_writes, d_reads)
                 # pinned-pool writes charge wear per token write (the
@@ -1283,6 +1305,9 @@ class PagedServingEngine:
                 n_reads = int(((positions[:, None] + np.arange(k)[None, :])
                                // page + 1).sum())
                 store.charge_fast_accesses(page_writes, n_reads)
+                # every inner step's read of a live page is one page the
+                # paged-attention kernel fetched, per layer
+                self._publish_page_fetches(n_reads, k * Bp * P)
             else:
                 page_reads = self._page_read_counts(positions, page_tables,
                                                     k)

@@ -200,11 +200,5 @@ class PagedKVCache:
         self.store.writes_to[t] += 1
         self.store.bump_version(pid)
 
-    def layer_pools(self, layer: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-        """(k_pool, v_pool) views [n_fast_slots, page, Hkv, Dh] for the
-        paged_attention kernel."""
-        return (self.store.fast_pool[:, layer, 0],
-                self.store.fast_pool[:, layer, 1])
-
     def occupancy(self) -> dict:
         return self.store.occupancy()

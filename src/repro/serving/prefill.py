@@ -226,9 +226,8 @@ class PrefillRunner:
                 fast_pool = fast_pool.at[write_slot, l, 1, write_off].set(
                     v[0].astype(dtype), mode="drop")
             with jax.named_scope("attention"):
-                out = paged_attention_prefill(q[0], fast_pool[:, l, 0],
-                                              fast_pool[:, l, 1], row_tables,
-                                              lengths)
+                out = paged_attention_prefill(q[0], fast_pool, l,
+                                              row_tables, lengths)
                 out = jnp.einsum("bhk,hkd->bd", out.reshape(
                     Lb, cfg.n_heads, cfg.head_dim), p.wo)[None, :, :]
                 h = h + out

@@ -3,6 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flash_attention import flash_attention, flash_attention_ref
 from repro.kernels.hotness_update import (sysmon_pass, sysmon_pass_ref,
@@ -41,28 +42,58 @@ def test_flash_attention_sweep(B, S, Hq, Hkv, D, causal, window, dtype):
                                atol=TOL[dtype], rtol=TOL[dtype])
 
 
-# --- paged decode attention -----------------------------------------------------
+# --- paged attention -----------------------------------------------------------
 
-@pytest.mark.parametrize("B,Hq,Hkv,D,page,n_pages", [
-    (3, 8, 2, 64, 16, 4), (2, 4, 4, 128, 8, 8), (1, 16, 2, 64, 32, 2),
-])
+_LIVE = "random"         # lengths drawn in [1, page * n_pages]
+
+
+@pytest.mark.parametrize("B,Hq,Hkv,D,page,n_pages,n_layers,layer,lengths", [
+    (3, 8, 2, 64, 16, 4, 1, 0, _LIVE),
+    (2, 4, 4, 128, 8, 8, 1, 0, _LIVE),
+    (1, 16, 2, 64, 32, 2, 1, 0, _LIVE),
+    # a layer past the first of a 3-layer pool
+    (3, 8, 2, 64, 16, 4, 3, 2, _LIVE),
+    # rows of length 0, 1 and exact page multiples
+    (5, 8, 2, 64, 16, 4, 2, 1, (0, 1, 16, 64, 0)),
+    # more live pages than one compute block holds (8 pages of 16 x 8)
+    (3, 16, 8, 128, 16, 20, 2, 1, (300, 129, 128)),
+    # head_dim 96
+    (2, 32, 32, 96, 16, 5, 2, 1, (70, 33)),
+], ids=["b3", "b2", "b1", "layer2", "edge-lengths", "multi-block", "d96"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_attention_sweep(B, Hq, Hkv, D, page, n_pages, dtype):
+def test_paged_attention_sweep(B, Hq, Hkv, D, page, n_pages, n_layers, layer,
+                               lengths, dtype):
+    """The Pallas kernel (interpreted, DMAs and semaphores emulated)
+    against the gather reference, reading one layer of a whole
+    [slots, layers, 2, page, Hkv, D] pool through per-row tables whose
+    columns past a row's context are padded with slot 0."""
+    from repro.kernels.paged_attention.paged_attention import pages_per_block
     n_slots = B * n_pages + 7
     ks = jax.random.split(jax.random.PRNGKey(1), 4)
     q = jax.random.normal(ks[0], (B, Hq, D), dtype)
-    kp = jax.random.normal(ks[1], (n_slots, page, Hkv, D), dtype)
-    vp = jax.random.normal(ks[2], (n_slots, page, Hkv, D), dtype)
-    bt = jax.random.permutation(ks[3], n_slots)[:B * n_pages]
-    bt = bt.reshape(B, n_pages).astype(jnp.int32)
-    lengths = jnp.asarray(
-        np.random.RandomState(0).randint(1, page * n_pages + 1, B), jnp.int32)
-    out = paged_attention(q, kp, vp, bt, lengths, interpret=True)
+    pool = jax.random.normal(
+        ks[1], (n_slots, n_layers, 2, page, Hkv, D), dtype)
+    if lengths == _LIVE:
+        lengths = np.random.RandomState(0).randint(1, page * n_pages + 1, B)
+    lengths = np.asarray(lengths, np.int32)
+    bt = np.asarray(jax.random.permutation(ks[3], n_slots - 1) + 1)
+    bt = bt[:B * n_pages].reshape(B, n_pages).astype(np.int32)
+    live = np.arange(n_pages)[None, :] < -(-lengths[:, None] // page)
+    bt = np.where(live, bt, 0)                       # padded with slot 0
+    if n_pages > 8:
+        assert -(-lengths.max() // page) > pages_per_block(page, Hkv,
+                                                           n_pages)
+    out = paged_attention(q, pool, layer, jnp.asarray(bt),
+                          jnp.asarray(lengths),
+                          interpret=pltpu.InterpretParams())
+    out = np.asarray(out, np.float32)
     G = Hq // Hkv
     qg = (q * jnp.asarray(D ** -0.5, dtype)).reshape(B, Hkv, G, D)
-    ref = paged_attention_ref(qg, kp, vp, bt, lengths).reshape(B, Hq, D)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
+    ref = paged_attention_ref(qg, pool, jnp.int32(layer), jnp.asarray(bt),
+                              jnp.asarray(lengths)).reshape(B, Hq, D)
+    assert not np.isnan(out).any()
+    np.testing.assert_array_equal(out[lengths == 0], 0.0)
+    np.testing.assert_allclose(out, np.asarray(ref, np.float32),
                                atol=TOL[dtype], rtol=TOL[dtype])
 
 

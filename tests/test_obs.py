@@ -528,3 +528,25 @@ def test_padded_work_counters(served):
         < counters["serving.decode_row_slots"]
     assert counters["serving.prefill_tokens"] == sum(map(len, PROMPTS)) \
         <= counters["serving.prefill_slots"]
+
+
+def test_page_fetch_counters(served):
+    """The paged-attention kernel fetches, per layer, each row's live
+    pages: ceil(length / page) for every prefilled position and every
+    decode step; the slots are every padded row's whole table."""
+    from repro.configs import registry, smoke
+    reqs, events, counters = served
+    page, n_pages = 8, 8                       # the served engine's
+    n_layers = smoke(registry()["qwen3_4b"]).n_layers
+    ks = [e.attrs["k"] for e in _spans(events, "serve.dispatch")]
+    buckets = [e.attrs["bucket"] for e in _spans(events, "serve.prefill")]
+    live = lambda n: -(-n // page)             # noqa: E731
+    prefill = sum(live(n) for p in PROMPTS for n in range(1, len(p) + 1))
+    # decode step at position pos attends to pos + 1 tokens; a request's
+    # first token comes from prefill, the other max_new - 1 from decode
+    decode = sum(live(pos + 1) for r in reqs for pos in
+                 range(len(r.prompt), len(r.prompt) + r.max_new - 1))
+    assert counters["serving.attn_pages_fetched"] == \
+        n_layers * (prefill + decode)
+    assert counters["serving.attn_page_slots"] == \
+        n_layers * n_pages * (sum(ks) * MAX_BATCH + sum(buckets))

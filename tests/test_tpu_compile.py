@@ -15,6 +15,7 @@ be read back without one.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -84,20 +85,53 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _producers(hlo: str) -> dict[str, tuple[str, str]]:
+    """Instruction name -> (opcode, operand list) of compiled HLO text."""
+    out = {}
+    for m in re.finditer(r"%([\w.-]+) = \S+(?: \S+)*? ([\w-]+)\(([^)]*)\)",
+                         hlo):
+        out[m.group(1)] = (m.group(2), m.group(3))
+    return out
+
+
 @pytest.mark.parametrize("rows", [4, 512], ids=["decode", "prefill"])
 @pytest.mark.parametrize("dtype", KV_DTYPES, ids=["bf16", "f32"])
-def test_paged_attention_compiles(one_chip, rows, dtype):
-    from repro.kernels.paged_attention.paged_attention import \
-        paged_attention_pooled
+def test_paged_attention_compiles(one_chip, page_shape, as_tpu, rows,
+                                  dtype):
+    """The kernel over the whole ``[slots, layers, 2, page, Hkv, D]`` pool
+    with a ``layer`` operand, inside a program shaped like the decode
+    step: per layer, the K/V append into the donated pool, then
+    attention.  The kernel must read the pool in place — its pool
+    operand a bitcast of the appended pool, no slice or copy of a layer
+    plane or of the pool anywhere — and keep its trace name."""
+    from repro.kernels.paged_attention import paged_attention
     hkv, d = CFG.n_kv_heads, CFG.head_dim
-    g = CFG.n_heads // hkv
-    pool = _spec((FAST_SLOTS, PAGE, hkv, d), dtype, one_chip)
+    pool = _spec((FAST_SLOTS, *page_shape), dtype, one_chip)
+
+    def step(pool, q, kv_new, slot, off, tables, lengths):
+        for layer in range(2):
+            pool = pool.at[slot, layer, 0, off].set(kv_new, mode="drop")
+            pool = pool.at[slot, layer, 1, off].set(kv_new, mode="drop")
+            q = q + paged_attention(q, pool, layer, tables, lengths)
+        return q, pool
+
+    i32 = lambda *shape: _spec(shape, jnp.int32, one_chip)  # noqa: E731
     _, hlo = _compile(
-        paged_attention_pooled,
-        _spec((rows, hkv, g, d), jnp.bfloat16, one_chip), pool, pool,
-        _spec((rows, MAX_PAGES), jnp.int32, one_chip),
-        _spec((rows,), jnp.int32, one_chip))
-    assert "tpu_custom_call" in hlo
+        step, pool, _spec((rows, CFG.n_heads, d), jnp.bfloat16, one_chip),
+        _spec((rows, hkv, d), dtype, one_chip), i32(rows), i32(rows),
+        i32(rows, MAX_PAGES), i32(rows), donate_argnums=(0,))
+    calls = re.findall(r"%(paged_attention(?:\.\d+)?) = .*custom-call\("
+                       r"([^)]*)\).*tpu_custom_call", hlo)
+    assert len(calls) == 2, "the kernel must trace as paged_attention"
+    ops = _producers(hlo)
+    for _, operands in calls:
+        pool_arg = operands.split(",")[-1].strip().lstrip("%")
+        assert ops[pool_arg][0] == "bitcast", ops[pool_arg]
+    planes = [f"[{FAST_SLOTS},{PAGE},{hkv},{d}]",
+              f"[{FAST_SLOTS},{PAGE * hkv},{d}]"]
+    assert not any(p in hlo for p in planes)
+    whole = "[" + ",".join(map(str, (FAST_SLOTS, *page_shape))) + "]"
+    assert not re.search(re.escape(whole) + r"\{[^}]*\} copy", hlo)
 
 
 @pytest.mark.parametrize("dtype", KV_DTYPES, ids=["bf16", "f32"])
