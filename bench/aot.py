@@ -56,7 +56,7 @@ def main(argv=None) -> int:
 
     hbm = args.hbm_pages or cell.cell["hbm_pages"]
     scfg = engine_config(cell, hbm_pages=1, host_pages=1)
-    absp = weights.abstract_params(cfg)
+    absp = cell.model.abstract_params(cfg)
     shard = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,  # noqa: E731
                                            sharding=dev)
     params = jax.tree.map(shard, absp)

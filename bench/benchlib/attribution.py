@@ -17,11 +17,9 @@ is the offset.  The marker starts a few microseconds before the span, so
 the spans land that much early on the trace: negligible against
 executions of milliseconds.
 
-The padded-work shares read the program's counters from its registry,
-which the harness resets as the window opens.  They are the window's
-counts only while nothing has published since it closed, so a share is
-read only where the registry's ``serving.dispatches`` still equals the
-window's count that the record carries.
+The padded-work shares read the program's counters as the window
+closed (``RunRecord.counters``; the harness resets the registry as the
+window opens).
 
 Each reader returns None where the run has nothing to read: no device
 trace or not a TPU, or a program without the spans or counters read.
@@ -129,17 +127,25 @@ def decode_device_ms_per_step(rec):
     return t / 1e6 / ks if ks and t > 0 else None
 
 
+def decode_kernel_roofline(rec, pattern: str, work):
+    """Least time of ``work(rec, steps)`` for the traced steps' decode
+    rows alone (``costs.min_seconds``) over the device time of the
+    kernels matching ``pattern`` inside the decode executions, in
+    percent."""
+    if not _traced(rec) or not rec.traced_steps:
+        return None
+    t = device_ns(ops_in(rec.trace, executions(rec, DECODE), pattern)) / 1e9
+    if t <= 0:
+        return None
+    steps = [costs.StepWork(decode=s.decode) for s in rec.traced_steps]
+    return 100.0 * costs.min_seconds(work(rec, steps), rec.peaks) / t
+
+
 def paged_attention_decode_roofline(rec):
     """Least time of the traced steps' live-context decode attention over
     the kernel's device time inside the decode executions, in percent."""
-    if not _traced(rec) or not rec.traced_steps:
-        return None
-    t = device_ns(ops_in(rec.trace, executions(rec, DECODE),
-                         PAGED_ATTENTION)) / 1e9
-    if t <= 0:
-        return None
-    work = [costs.StepWork(decode=s.decode) for s in rec.traced_steps]
-    return 100.0 * costs.attention_min_seconds(rec.cfg, work, rec.peaks) / t
+    return decode_kernel_roofline(rec, PAGED_ATTENTION,
+                                  rec.model.attention_work)
 
 
 def host_idle_share(rec):
@@ -159,25 +165,20 @@ def host_idle_share(rec):
     return 100.0 * (1.0 - sum(b - a for a, b in covered) / (hi - lo))
 
 
-def _counter(name: str):
-    from repro import obs
-    m = obs.get_registry().collect().get(name)
-    return m.value if isinstance(m, obs.Counter) else None
-
-
-def _share(rec, num: str, den: str):
-    if _counter("serving.dispatches") != rec.dispatches:
-        return None          # the registry is no longer the window's
-    n, d = _counter(num), _counter(den)
+def counter_share(rec, num: str, den: str):
+    """100 x counter ``num`` / counter ``den``, as the window closed."""
+    n, d = rec.counters.get(num), rec.counters.get(den)
     return 100.0 * n / d if n is not None and d else None
 
 
 def decode_live_row_share(rec):
     """Live rows of the decode dispatches' padded row-steps, in percent."""
-    return _share(rec, "serving.tokens_sampled", "serving.decode_row_slots")
+    return counter_share(rec, "serving.tokens_sampled",
+                         "serving.decode_row_slots")
 
 
 def prefill_fill_share(rec):
     """Prompt tokens of the prefill dispatches' bucket positions, in
     percent."""
-    return _share(rec, "serving.prefill_tokens", "serving.prefill_slots")
+    return counter_share(rec, "serving.prefill_tokens",
+                         "serving.prefill_slots")

@@ -23,19 +23,11 @@ first.
 """
 from __future__ import annotations
 
-import importlib.util
 import time
 
 import numpy as np
 
-
-def _reference_module(cell):
-    path = cell.bench_dir / "references" / f"{cell.config['reference']}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_reference_{path.stem}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+from .spec import load_module
 
 
 def sample(keep: list, n: int, seed: int) -> list:
@@ -61,7 +53,8 @@ def compare(cell, params, keep: list, seed: int, *,
     """(checks, lines): each compared number with its limit, and what to
     print before the result.  ``keep`` holds (rid, prompt, served tokens,
     moved) of every request the window finished."""
-    mod = _reference_module(cell)
+    mod = load_module(cell.bench_dir / "references"
+                      / f"{cell.config['reference']}.py")
     sizes = cell.config["serve_as"]
     T = cell.traffic["max_pages_per_seq"] * cell.config["engine"]["page_size"]
     chosen = sample(keep, int(cell.traffic["check_requests"]), seed)

@@ -56,17 +56,24 @@ def device_idle_share(rec):
 PAGED_ATTENTION = r"^paged_attention$"
 
 
-def paged_attention_roofline(rec):
-    """Least time of the live-context attention work of the traced steps
-    over the kernel's device time in the trace, in percent."""
+def kernel_roofline(rec, pattern: str, work):
+    """Least time of ``work(rec, steps)`` for the traced steps
+    (``costs.min_seconds``) over the device time of the kernels whose
+    trace name matches ``pattern``, in percent."""
     if not _traced(rec) or not rec.traced_steps:
         return None
-    t = profile.kernel_ns(rec.trace, PAGED_ATTENTION, rec.trace_lo,
+    t = profile.kernel_ns(rec.trace, pattern, rec.trace_lo,
                           rec.trace_hi) / 1e9
     if t <= 0:
         return None
-    need = costs.attention_min_seconds(rec.cfg, rec.traced_steps, rec.peaks)
-    return 100.0 * need / t
+    return 100.0 * costs.min_seconds(work(rec, rec.traced_steps),
+                                     rec.peaks) / t
+
+
+def paged_attention_roofline(rec):
+    """Least time of the live-context attention work of the traced steps
+    over the kernel's device time in the trace, in percent."""
+    return kernel_roofline(rec, PAGED_ATTENTION, rec.model.attention_work)
 
 
 def step_mfu(rec):
@@ -75,5 +82,5 @@ def step_mfu(rec):
     if not _traced(rec) or not rec.traced_steps:
         return None
     window = (rec.trace_hi - rec.trace_lo) / 1e9
-    f = costs.model_flops(rec.cfg, rec.traced_steps)
+    f = rec.model.model_flops(rec, rec.traced_steps)
     return 100.0 * f / (window * rec.peaks["bf16_flops"]) if f else None

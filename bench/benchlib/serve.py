@@ -12,7 +12,6 @@ submitted; each request is timed from its due time.
 from __future__ import annotations
 
 import gc
-import importlib.util
 import math
 import shutil
 import time
@@ -24,7 +23,7 @@ import numpy as np
 from . import costs, profile, traffic, weights
 from .meter import CompileMeter
 from .peaks import peaks_for
-from .spec import Cell, arch_config
+from .spec import Cell, arch_config, load_module
 
 # longest device trace taken in a traced run, in seconds of the window
 PROFILE_S = 10.0
@@ -64,23 +63,17 @@ class Served:
 class RunRecord:
     """Everything a per-layer metric reader may read (see bench/metrics)."""
     cfg: object
+    model: object                       # the configuration's model module
     platform: str
     peaks: dict | None
     steps: list[costs.StepWork]         # engine steps in the window
     spans: list                         # repro.obs span events, window
     dispatches: int                     # decode dispatches in the window
+    counters: dict = field(default_factory=dict)  # the window's counters
     trace: object = None                # profile.Trace, traced TPU runs
     trace_lo: float = 0.0               # traced window, trace clock (ns)
     trace_hi: float = 0.0
     traced_steps: list = field(default_factory=list)
-
-
-def _load_module(path: Path):
-    spec = importlib.util.spec_from_file_location(
-        "bench_" + path.stem.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def _warm(eng, cfg, cell, seed) -> None:
@@ -111,7 +104,7 @@ def _warm(eng, cfg, cell, seed) -> None:
         jax.block_until_ready(pool.data)
     # the decode dispatch hands back ``logits[:B]`` for the live batch B:
     # one slice program per B
-    logits = jnp.zeros((eng.scfg.max_batch, weights.padded_vocab(cfg)),
+    logits = jnp.zeros((eng.scfg.max_batch, cell.model.padded_vocab(cfg)),
                        jnp.float32)
     for b in range(1, eng.scfg.max_batch + 1):
         logits[:b].block_until_ready()
@@ -157,7 +150,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     mix = cell.traffic
     lines: list[str] = []
 
-    params = weights.make_params(cfg, seed)
+    params = cell.model.make_params(cfg, seed)
     jax.block_until_ready(params)
     t_weights = time.perf_counter() - t_setup0
     eng = PagedServingEngine(cfg, params, scfg)
@@ -336,10 +329,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         e2e["ttft_p95_s"] = float(np.percentile(ttft, 95))
     if tpot:
         e2e["tpot_p95_ms"] = float(np.percentile(tpot, 95)) * 1e3
-    rec = RunRecord(cfg=cfg, platform=dev.platform,
+    rec = RunRecord(cfg=cfg, model=cell.model, platform=dev.platform,
                     peaks=(peaks_for(dev.device_kind)
                            if dev.platform == "tpu" else None),
-                    steps=steps, spans=spans, dispatches=dispatches)
+                    steps=steps, spans=spans, dispatches=dispatches,
+                    counters=counters)
     n_due, n_failed = len(due_in), len(failed)
     keep = [(s.rid, list(s.prompt), list(s.req.generated), s.moved)
             for s in done_in]
@@ -421,7 +415,7 @@ def _per_layer(cell: Cell, rec: RunRecord) -> dict:
     the metric is left out."""
     out = {}
     for m in cell.per_layer():
-        mod = _load_module(cell.bench_dir / "metrics" / f"{m['name']}.py")
+        mod = load_module(cell.bench_dir / "metrics" / f"{m['name']}.py")
         v = mod.read(rec)
         if v is not None and math.isfinite(v):
             out[m["name"]] = {"value": float(v), "unit": m["unit"]}

@@ -1,14 +1,17 @@
 """Finds a cell's files by name: the workload entry in ``BENCHMARK.json``,
-its configuration (``configs/<config>.json``), its traffic mix
-(``traffic/<mix>.json``) and its cell settings (``cells/<workload>.json``:
-offered rate, tier sizes and the limits of the correctness check).
+its configuration (``configs/<config>.json``), the configuration's model
+module (``models/<reference>.py``), its traffic mix (``traffic/<mix>.json``)
+and its cell settings (``cells/<workload>.json``: offered rate, tier
+sizes and the limits of the correctness check).
 
 Nothing here knows a model, a mix or a metric by name: a later cell
 brings its own files and an entry in ``BENCHMARK.json``."""
 from __future__ import annotations
 
+import importlib.util
 import json
 from dataclasses import dataclass
+from functools import cached_property
 from pathlib import Path
 
 BENCH_DIR = Path(__file__).resolve().parents[1]
@@ -30,6 +33,30 @@ class Cell:
     @property
     def bench_dir(self) -> Path:
         return self.root / "bench"
+
+    @cached_property
+    def model(self):
+        """The configuration's model module, ``models/<reference>.py``:
+        one name, the configuration's ``reference``, picks both the model
+        module and the plain reference (``references/<reference>.py``).
+        The module gives the harness everything that depends on the kind
+        of model:
+
+        * ``make_params(cfg, seed)``: the parameter tree the program
+          reads, drawn from the seed on the device in the served dtype;
+          ``abstract_params(cfg)``: its shapes, drawing nothing;
+        * ``padded_vocab(cfg)``: the width of the program's logits;
+        * ``attention_work(rec, steps)``: the paged-attention kernel
+          calls of ``steps`` as ``(layers, [(flops, bytes), ...])``, each
+          of ``layers`` layers making every call (``costs.min_seconds``);
+        * ``model_flops(rec, steps)``: the model FLOPs of the tokens
+          ``steps`` processed.
+
+        ``cfg`` is the program's ``ArchConfig``; ``rec`` the run's
+        ``RunRecord``, whose ``counters`` hold what the program counted
+        (the expert work a routed model did, say)."""
+        return load_module(self.bench_dir / "models"
+                           / f"{self.config['reference']}.py")
 
     def end_to_end(self) -> list[dict]:
         """End-to-end metrics this cell reports (``setup_s`` and those
@@ -66,6 +93,17 @@ def load_cell(workload: str, root: Path | None = None) -> Cell:
                          .read_text())
     cell = json.loads((bdir / "cells" / f"{workload}.json").read_text())
     return Cell(root, bench, wl, cfg, traffic, cell)
+
+
+def load_module(path: Path):
+    """A benchmark file (a model, a reference, a metric reader) as a
+    module of its own, by its path."""
+    spec = importlib.util.spec_from_file_location(
+        "bench_" + "_".join(path.parts[-2:]).removesuffix(".py")
+        .replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def arch_config(config: dict):

@@ -3,11 +3,10 @@ fetched, as a share of the (padded row, table column) pairs a walk of
 every row's whole block table visits, over the single-pool decode and
 prefill calls of the window (all layers).  Read from the program's
 counters ``serving.attn_pages_fetched`` and ``serving.attn_page_slots``
-under the same registry guard as ``decode.live_row_share``; a program
-without them gives nothing."""
-from benchlib.attribution import _share
+as the window closed; a program without them gives nothing."""
+from benchlib.attribution import counter_share
 
 
 def read(rec):
-    return _share(rec, "serving.attn_pages_fetched",
-                  "serving.attn_page_slots")
+    return counter_share(rec, "serving.attn_pages_fetched",
+                         "serving.attn_page_slots")

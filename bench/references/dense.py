@@ -80,7 +80,7 @@ class Reference:
         eps = c["norm_eps"]
         lp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
             a, l, keepdims=False), layers)
-        a, m = lp["attn"], lp["mlp"]
+        a = lp["attn"]
         f32 = lambda v: v.astype(jnp.float32)  # noqa: E731
         T = h.shape[0]
         pos = jnp.arange(T)
@@ -102,13 +102,17 @@ class Reference:
                        precision=HI)
         h = h + jnp.einsum("thk,hkd->td", o, self._mat(a["wo"], (0, 1)),
                            precision=HI)
-        x = self._rms(h, f32(lp["ln2"]), eps)
+        return h + self._ffn(lp, self._rms(h, f32(lp["ln2"]), eps))
+
+    def _ffn(self, lp, x):
+        """The layer's SwiGLU MLP over the normed hidden states [T, d]."""
+        m = lp["mlp"]
         gate = jnp.einsum("td,df->tf", x, self._mat(m["w_gate"], 0),
                           precision=HI)
         up = jnp.einsum("td,df->tf", x, self._mat(m["w_up"], 0),
                         precision=HI)
-        return h + jnp.einsum("tf,fd->td", jax.nn.silu(gate) * up,
-                              self._mat(m["w_down"], 0), precision=HI)
+        return jnp.einsum("tf,fd->td", jax.nn.silu(gate) * up,
+                          self._mat(m["w_down"], 0), precision=HI)
 
     def _embed_fn(self, embed, tokens):
         return self._mat(embed, 1)[tokens]

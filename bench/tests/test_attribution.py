@@ -18,6 +18,8 @@ from benchlib import costs, profile, readers
 from benchlib.peaks import PEAKS
 from benchlib.profile import OpEvent, Trace
 from benchlib.serve import RunRecord, _step_work
+from benchlib.spec import load_module
+from conftest import BENCH
 
 from repro import obs
 from repro.obs.trace import SpanEvent
@@ -26,6 +28,11 @@ V5E = PEAKS["TPU v5 lite"]
 OFF = 5_000_000_000          # trace clock minus span clock, hand-built run
 CFG = types.SimpleNamespace(n_heads=32, n_kv_heads=8, head_dim=128,
                             n_layers=36, d_model=2560, d_ff=9728)
+DENSE = load_module(BENCH / "models" / "dense.py")
+# the padded-work counters of _hand_run's window
+WINDOW = {"serving.dispatches": 2, "serving.tokens_sampled": 30,
+          "serving.decode_row_slots": 48, "serving.prefill_tokens": 140,
+          "serving.prefill_slots": 256}
 
 
 @pytest.fixture(autouse=True)
@@ -41,7 +48,7 @@ def _span(name, a, b, **attrs):
     return SpanEvent(name, "X", a, b - a, 1, attrs or None)
 
 
-def _hand_run(spans=None, platform="tpu"):
+def _hand_run(spans=None, platform="tpu", counters=None):
     """Two engine steps on the span clock, their device ops on the trace
     clock.  Step 0 prefills 140 prompt tokens and decodes K=8; step 1
     decodes K=4."""
@@ -67,8 +74,9 @@ def _hand_run(spans=None, platform="tpu"):
              OpEvent("bench.step.1", OFF + 4000, OFF + 6200, "/host:CPU")]
     steps = [costs.StepWork(prefill=[140], decode=[(140, 8)]),
              costs.StepWork(decode=[(148, 4)])]
-    return RunRecord(cfg=CFG, platform=platform, peaks=V5E, steps=steps,
-                     spans=spans, dispatches=2, trace=Trace(ops, marks),
+    return RunRecord(cfg=CFG, model=DENSE, platform=platform, peaks=V5E,
+                     steps=steps, spans=spans, dispatches=2,
+                     counters=counters or {}, trace=Trace(ops, marks),
                      trace_lo=OFF - 100, trace_hi=OFF + 7000,
                      traced_steps=steps)
 
@@ -84,7 +92,8 @@ def test_hand_built_run_split_and_readers():
     decode_only = [costs.StepWork(decode=[(140, 8)]),
                    costs.StepWork(decode=[(148, 4)])]
     assert A.paged_attention_decode_roofline(rec) == pytest.approx(
-        100 * costs.attention_min_seconds(CFG, decode_only, V5E) / 2000e-9)
+        100 * costs.min_seconds(DENSE.attention_work(rec, decode_only), V5E)
+        / 2000e-9)
     # idle 2710 ns of the 7100 ns window; 500 ns of it inside the waits
     assert A.host_idle_share(rec) == pytest.approx(100 * 2210 / 7100)
     assert readers.device_idle_share(rec) == pytest.approx(100 * 2710 / 7100)
@@ -108,36 +117,31 @@ def test_readers_find_nothing_without_what_they_read():
     assert A.trace_offset(_hand_run(spans=dropped)) is None
     assert A.decode_device_ms_per_step(_hand_run(spans=dropped)) is None
     # the window's dispatches, but no counters of padded work
-    obs.get_registry().counter("serving.dispatches").inc(2)
-    assert A.decode_live_row_share(_hand_run()) is None
-    assert A.prefill_fill_share(_hand_run()) is None
-
-
-def _publish_window(reg):
-    reg.counter("serving.dispatches").inc(2)       # as _hand_run's record
-    reg.counter("serving.tokens_sampled").inc(30)
-    reg.counter("serving.decode_row_slots").inc(48)
-    reg.counter("serving.prefill_tokens").inc(140)
-    reg.counter("serving.prefill_slots").inc(256)
+    rec = _hand_run(counters={"serving.dispatches": 2})
+    assert A.decode_live_row_share(rec) is None
+    assert A.prefill_fill_share(rec) is None
 
 
 def test_padded_work_shares_read_the_program_counters():
-    _publish_window(obs.get_registry())
-    rec = _hand_run()
+    rec = _hand_run(counters=WINDOW)
     assert A.decode_live_row_share(rec) == pytest.approx(62.5)
     assert A.prefill_fill_share(rec) == pytest.approx(100 * 140 / 256)
 
 
 def test_padded_work_shares_refuse_a_registry_past_the_window():
+    """The shares read the counters the record took as the window closed,
+    not the program's live registry, which may have counted past it."""
     reg = obs.get_registry()
-    _publish_window(reg)
-    # a dispatch published after the window closed: the registry no
-    # longer holds the window's counts
+    for name, v in WINDOW.items():
+        reg.counter(name).inc(v)
+    # a dispatch published after the window closed
     reg.counter("serving.dispatches").inc()
     reg.counter("serving.tokens_sampled").inc(8)
     reg.counter("serving.decode_row_slots").inc(8)
+    rec = _hand_run(counters=WINDOW)
+    assert A.decode_live_row_share(rec) == pytest.approx(62.5)
+    assert A.prefill_fill_share(rec) == pytest.approx(100 * 140 / 256)
     assert A.decode_live_row_share(_hand_run()) is None
-    assert A.prefill_fill_share(_hand_run()) is None
 
 
 def _modules(xplane) -> dict:
@@ -187,7 +191,7 @@ def test_span_attribution_matches_the_ops_modules(tmp_path):
     xplane = profile.find_xplane(tmp_path)
     tr = profile.load(xplane, plane_prefix="/host:CPU")
     spans = obs.get_tracer().events()
-    rec = RunRecord(cfg=CFG, platform="tpu", peaks=V5E,
+    rec = RunRecord(cfg=CFG, model=DENSE, platform="tpu", peaks=V5E,
                     steps=[costs.StepWork() for _ in range(n_steps)],
                     spans=spans, dispatches=n_steps, trace=tr)
     off = A.trace_offset(rec)
@@ -221,7 +225,7 @@ def test_offset_matches_the_spans_in_the_trace(tmp_path):
         for e in ln.events if e.name == "serve.admit")
     tr = profile.load(profile.find_xplane(tmp_path), plane_prefix="/host:CPU")
     spans = obs.get_tracer().events()
-    rec = RunRecord(cfg=CFG, platform="tpu", peaks=V5E,
+    rec = RunRecord(cfg=CFG, model=DENSE, platform="tpu", peaks=V5E,
                     steps=[costs.StepWork()] * 3, spans=spans, dispatches=0,
                     trace=tr)
     off = A.trace_offset(rec)
@@ -275,8 +279,8 @@ def test_existing_readers_read_the_same_with_the_new_spans(tmp_path):
     tr = profile.load(profile.find_xplane(tmp_path), plane_prefix="/host:CPU")
 
     def record(sp):
-        return RunRecord(cfg=cfg, platform="tpu", peaks=V5E, steps=steps,
-                         spans=sp, dispatches=sum(
+        return RunRecord(cfg=cfg, model=DENSE, platform="tpu", peaks=V5E,
+                         steps=steps, spans=sp, dispatches=sum(
                              e.name == "serve.dispatch" for e in sp),
                          trace=tr, traced_steps=steps)
 

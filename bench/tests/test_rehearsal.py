@@ -58,6 +58,29 @@ def test_no_device_metric_from_a_cpu_run(smoke_root, capsys):
     assert "busy_s" not in res["device"] and "breakdown" not in res
 
 
+# padded-work shares and the counters each divides
+SHARES = {"decode.live_row_share.chat": ("serving.tokens_sampled",
+                                         "serving.decode_row_slots"),
+          "prefill.fill_share.chat": ("serving.prefill_tokens",
+                                      "serving.prefill_slots"),
+          "attention.page_fetch_share.chat": ("serving.attn_pages_fetched",
+                                              "serving.attn_page_slots")}
+
+
+def test_counter_shares_read_the_window_counters(smoke_root, capsys):
+    """The padded-work shares, read from the counters the record took as
+    the window closed, equal what the program's registry still holds
+    after the run, where the readers used to read them."""
+    from repro import obs
+    rc, res, err = run_json(smoke_root, ARGS + ["--trace", "1"], capsys)
+    assert rc == 0, err
+    reg = {n: m.value for n, m in obs.get_registry().collect().items()
+           if isinstance(m, obs.Counter)}
+    assert reg["serving.dispatches"] > 0
+    for name, (num, den) in SHARES.items():
+        assert res["metrics"][name]["value"] == 100.0 * reg[num] / reg[den]
+
+
 def _cli(root, *args):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     return subprocess.run([sys.executable, "bench/run.py", *args], cwd=root,
